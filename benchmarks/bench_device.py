@@ -48,7 +48,7 @@ import numpy as np
 
 from benchmarks.bench_scan import _best_of, _build_store, _workload
 from repro.analysis.flops import scan_estimate
-from repro.analysis.roofline import Roofline
+from repro.analysis.roofline import V5E, Roofline
 from repro.core.device_scan import DeviceScanner
 from repro.core.server import DataSkippingScanner
 from repro.kernels.scan_fused import scan_counts
@@ -127,6 +127,7 @@ def run(n_records: int = 24576, chunk_records: int = 512,
         mesh="1x1", device_flops=est.flops_global,
         device_bytes=est.hbm_bytes_global, collective_bytes=0.0,
         model_flops_global=est.flops_global, n_devices=1,
+        device_kind=V5E,
     ).finalize()
     roofline_frac = roof.step_time_s / launch_s
 

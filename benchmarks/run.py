@@ -191,6 +191,9 @@ def main() -> None:
             ap.error(f"unknown suite(s): {','.join(sorted(unknown))} "
                      f"(see --list)")
     os.makedirs("artifacts", exist_ok=True)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     csv_rows: list[tuple[str, float, str]] = []
 

@@ -18,10 +18,39 @@ import dataclasses
 import re
 from dataclasses import dataclass, field
 
-# TPU v5e per-chip constants (assignment-specified)
-PEAK_FLOPS = 197e12        # bf16 FLOP/s
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s effective per chip
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    bf16_flops: float      # FLOP/s
+    int8_ops: float        # OP/s
+    hbm_bw: float          # bytes/s
+    ici_bw: float          # bytes/s, chip-to-chip interconnect
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8,
+#: 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                             hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+#: ``device_kind`` of a TPU v5e chip (the dry-run's target)
+V5E = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a kind missing from :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to repro.analysis.roofline.PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -98,6 +127,7 @@ class Roofline:
     collective_bytes: float
     model_flops_global: float      # 6·N·D (train) or 2·N_active·tokens (decode)
     n_devices: int
+    device_kind: str               # key of PEAKS
     compute_s: float = 0.0
     memory_s: float = 0.0
     collective_s: float = 0.0
@@ -110,9 +140,10 @@ class Roofline:
     notes: str = ""
 
     def finalize(self) -> "Roofline":
-        self.compute_s = self.device_flops / PEAK_FLOPS
-        self.memory_s = self.device_bytes / HBM_BW
-        self.collective_s = self.collective_bytes / ICI_BW
+        peaks = chip_peaks(self.device_kind)
+        self.compute_s = self.device_flops / peaks.bf16_flops
+        self.memory_s = self.device_bytes / peaks.hbm_bw
+        self.collective_s = self.collective_bytes / peaks.ici_bw
         terms = {
             "compute": self.compute_s,
             "memory": self.memory_s,
@@ -126,7 +157,8 @@ class Roofline:
         # bound on step time: max of the three terms (perfect overlap);
         # roofline fraction = useful-compute time / bound.
         self.step_time_s = max(terms.values())
-        useful_compute_s = self.model_flops_global / (PEAK_FLOPS * self.n_devices)
+        useful_compute_s = self.model_flops_global / (
+            peaks.bf16_flops * self.n_devices)
         self.roofline_frac = (
             useful_compute_s / self.step_time_s if self.step_time_s else 0.0
         )

@@ -13,14 +13,9 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax.numpy as jnp
 import numpy as np
-
-try:  # jnp flavor is optional at import time (host-only tools).
-    import jax.numpy as jnp
-    from jax import lax
-except Exception:  # pragma: no cover
-    jnp = None
-    lax = None
+from jax import lax
 
 WORD_BITS = 32
 
@@ -76,34 +71,16 @@ def bv_or_many(words: np.ndarray) -> np.ndarray:
     return np.bitwise_or.reduce(np.asarray(words, dtype=np.uint32), axis=0)
 
 
-def _popcount_rows_unpack(words: np.ndarray) -> np.ndarray:
-    """np.bitwise_count-free per-row popcount (numpy < 2.0)."""
+def popcount_rows(words: np.ndarray) -> np.ndarray:
+    """int64[P]: per-row popcount of uint32[P, W]."""
     w = np.ascontiguousarray(np.asarray(words, dtype=np.uint32))
     if w.size == 0:
         return np.zeros((w.shape[0],), np.int64)
-    bytes_ = w.view(np.uint8).reshape(w.shape[0], -1)
-    return np.unpackbits(bytes_, axis=1).sum(axis=1, dtype=np.int64)
-
-
-if hasattr(np, "bitwise_count"):
-    def popcount_rows(words: np.ndarray) -> np.ndarray:
-        """int64[P]: per-row popcount of uint32[P, W]."""
-        w = np.ascontiguousarray(np.asarray(words, dtype=np.uint32))
-        if w.size == 0:
-            return np.zeros((w.shape[0],), np.int64)
-        return np.bitwise_count(w).sum(axis=1, dtype=np.int64)
-else:  # pragma: no cover — exercised via the _popcount_unpack regression test
-    popcount_rows = _popcount_rows_unpack
+    return np.bitwise_count(w).sum(axis=1, dtype=np.int64)
 
 
 def popcount(words: np.ndarray) -> int:
     return int(popcount_rows(np.asarray(words, np.uint32).reshape(1, -1)).sum())
-
-
-def _popcount_unpack(words: np.ndarray) -> int:
-    """Fallback-path popcount, exposed for the numpy<2 regression test."""
-    return int(_popcount_rows_unpack(
-        np.asarray(words, np.uint32).reshape(1, -1)).sum())
 
 
 def select_indices(words: np.ndarray, n_records: int) -> np.ndarray:

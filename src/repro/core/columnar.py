@@ -581,6 +581,12 @@ class SegmentBuilder:
     amortize per-segment Python overhead).  ``view()`` materializes the
     open tail as a segment for the query path, cached until the next
     append, so scans between ingests pay the column build once.
+
+    Readers call ``view()`` without the store's ingest lock while a
+    writer appends: ``add`` appends the bit matrix LAST and then bumps a
+    version, ``_build`` sizes the view by the bits it saw (a consistent
+    prefix, copied — never the live lists), and a view cached under an
+    older version is rebuilt.
     """
 
     epoch: int
@@ -594,7 +600,8 @@ class SegmentBuilder:
         self._records: list[bytes] = []
         self._objs: list[dict] = []
         self._bits: list[np.ndarray] = []   # bool[n_covered, k] per chunk
-        self._view: ColumnarSegment | None = None
+        self._version = 0
+        self._view: tuple[int, ColumnarSegment] | None = None
 
     @property
     def n_rows(self) -> int:
@@ -607,22 +614,23 @@ class SegmentBuilder:
             raise ValueError(
                 f"bits shape {bits.shape} != ({self.n_covered}, "
                 f"{len(records)})")
-        self._view = None
         self._records.extend(records)
         self._objs.extend(objs)
         self._bits.append(np.asarray(bits, bool))
+        self._version += 1
         if len(self._records) >= self.capacity:
             return [self.seal()]
         return []
 
     def _build(self) -> ColumnarSegment:
-        n = len(self._records)
-        if self._bits:
-            bits = np.concatenate(self._bits, axis=1)
+        parts = list(self._bits)
+        n = sum(b.shape[1] for b in parts)
+        if parts:
+            bits = np.concatenate(parts, axis=1)
         else:
             bits = np.zeros((self.n_covered, n), bool)
         return ColumnarSegment(
-            records=self._records, objs=self._objs,
+            records=self._records[:n], objs=self._objs[:n],
             bitvectors=bitvector.pack(bits) if n else
             np.zeros((self.n_covered, 0), np.uint32),
             epoch=self.epoch, n_covered=self.n_covered, tier=self.tier,
@@ -631,15 +639,17 @@ class SegmentBuilder:
 
     def view(self) -> ColumnarSegment:
         """Query-path view of the open tail (cached until the next add)."""
-        if self._view is None:
-            self._view = self._build()
-        return self._view
+        version, cached = self._version, self._view
+        if cached is None or cached[0] != version:
+            cached = (version, self._build())
+            self._view = cached
+        return cached[1]
 
     def seal(self) -> ColumnarSegment:
         """Finalize and reset the builder."""
         seg = self._build()
         self._records, self._objs, self._bits = [], [], []
-        self._view = None
+        self._version += 1
         return seg
 
 

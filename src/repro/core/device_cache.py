@@ -93,10 +93,16 @@ class CacheSlot:
 
 
 class DeviceSegmentCache:
-    """Per-store device mirror of sealed + JIT-promoted segments."""
+    """Per-store device mirror of sealed + JIT-promoted segments.
 
-    def __init__(self, *, byte_budget: int = 256 << 20):
+    ``device`` pins the plane to one jax device (a sharded store puts each
+    shard's plane on its own chip); ``None`` uses the default device.
+    """
+
+    def __init__(self, *, byte_budget: int = 256 << 20,
+                 device: jax.Device | None = None):
         self.byte_budget = int(byte_budget)
+        self.device = device
         self._slots: dict[int, CacheSlot] = {}     # id(seg) -> slot
         self._order: list[CacheSlot] = []          # slot id order
         self._key_rows: dict[str, int] = {}        # key -> plane row (>= 1)
@@ -178,7 +184,7 @@ class DeviceSegmentCache:
         k_cap = bucket_pow2(len(self._key_rows) + 1, 2)
         n_new = sum(seg.n_rows for seg, _ in pairs)
         n_cap = bucket_pow2(self._n_used + n_new, _N_FLOOR)
-        self._ensure_capacity(k_cap, n_cap)
+        self.ensure_capacity(k_cap, n_cap)
         p = self._plane
         assert p is not None
         k_cap, n_cap = p.pres.shape
@@ -239,20 +245,23 @@ class DeviceSegmentCache:
     def _upload(self, arr: np.ndarray) -> jnp.ndarray:
         self.uploads += 1
         self.upload_bytes += arr.nbytes
-        return jnp.asarray(arr)
+        return jax.device_put(arr, self.device)
 
-    def _ensure_capacity(self, k_cap: int, n_cap: int) -> None:
+    def ensure_capacity(self, k_cap: int, n_cap: int) -> None:
+        """Grow the plane to at least ``(k_cap, n_cap)`` (device-side,
+        persistent; a no-op when it is already that large)."""
         p = self._plane
         if p is None:
+            dev = self.device
             self._plane = DevicePlaneArrays(
-                pres=jnp.zeros((k_cap, n_cap), jnp.uint8),
-                notn=jnp.zeros((k_cap, n_cap), jnp.uint8),
-                isb=jnp.zeros((k_cap, n_cap), jnp.uint8),
-                numv=jnp.zeros((k_cap, n_cap), jnp.uint8),
-                scod=jnp.full((k_cap, n_cap), -1, jnp.int32),
-                rcod=jnp.full((k_cap, n_cap), -1, jnp.int32),
-                sid=jnp.full((n_cap,), -1, jnp.int32),
-                cw=jnp.zeros((n_cap,), jnp.uint32),
+                pres=jnp.zeros((k_cap, n_cap), jnp.uint8, device=dev),
+                notn=jnp.zeros((k_cap, n_cap), jnp.uint8, device=dev),
+                isb=jnp.zeros((k_cap, n_cap), jnp.uint8, device=dev),
+                numv=jnp.zeros((k_cap, n_cap), jnp.uint8, device=dev),
+                scod=jnp.full((k_cap, n_cap), -1, jnp.int32, device=dev),
+                rcod=jnp.full((k_cap, n_cap), -1, jnp.int32, device=dev),
+                sid=jnp.full((n_cap,), -1, jnp.int32, device=dev),
+                cw=jnp.zeros((n_cap,), jnp.uint32, device=dev),
             )
             return
         ok, on = p.pres.shape

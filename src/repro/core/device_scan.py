@@ -22,13 +22,16 @@ labor per scan:
 :class:`~repro.core.shard.ShardedScanner`'s three-level cascade
 (partition prune -> per-shard scan -> deterministic
 ``merge_scan_results`` through ``dist.collectives.tree_reduce``) with a
-per-shard :class:`~repro.core.device_cache.DeviceSegmentCache`.  When
-every surviving shard can own a jax device
-(``dist.sharding.scan_mesh``), the per-shard launches collapse into one
-``shard_map`` SPMD program over a ``("shards",)`` mesh — shard planes
-are padded to common buckets, stacked, and each device evaluates its
-own shard's rows; otherwise shards launch sequentially with identical
-results.
+per-shard :class:`~repro.core.device_cache.DeviceSegmentCache` whose
+plane lives on the shard's own device (shard *s* on device *s*,
+round-robin when shards outnumber devices).  When every surviving shard
+owns a distinct device, the per-shard launches collapse into one
+``shard_map`` SPMD program over a ``("shards",)`` mesh of those devices:
+the global plane arrays are assembled from the per-device buffers
+(``jax.make_array_from_single_device_arrays`` — no restack, no copy
+across chips), and only the per-scan parameter tables travel.
+Otherwise shards launch sequentially, each on its own device, with
+identical results.
 
 Public contract, shared with every other scanner: ``ScanResult.groups``
 sorted by (epoch, tier), deterministic merge order, accounting
@@ -41,24 +44,23 @@ every scan is folded into the store's
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.device_cache import (
-    CacheSlot, DeviceSegmentCache, _grow1, _grow2,
-)
+from repro.core.device_cache import CacheSlot, DeviceSegmentCache
 from repro.core.predicates import Query
 from repro.core.server import CiaoStore, DataSkippingScanner, ScanResult
 from repro.core.shard import ShardedCiaoStore, merge_scan_results
-from repro.dist.sharding import scan_mesh
 from repro.kernels.scan_fused import (
-    DevicePlaneArrays, ScanBatch, ScanParams,
-    compile_scan_batch, scan_core_numpy, scan_core_xla, scan_counts,
+    KIND_SUBSTRING, ScanBatch, ScanParams, _scan_core_pallas, bucket_pow2,
+    compile_scan_batch, pallas_scan_args, scan_core_numpy, scan_core_xla,
+    scan_counts,
 )
 
 
@@ -81,16 +83,18 @@ class _Prepared:
 class DeviceScanner:
     """Device-plane scanner over a single :class:`CiaoStore`."""
 
-    def __init__(self, store: CiaoStore, *, backend: str = "xla",
+    def __init__(self, store: CiaoStore, *, backend: str = "pallas",
                  byte_budget: int = 256 << 20, log_queries: bool = True,
                  r_blk: int = 512, result_cache: "object | None" = None,
                  telemetry: "object | bool | None" = None,
-                 tenant: str = "default"):
+                 tenant: str = "default",
+                 device: jax.Device | None = None):
         self.store = store
         self.backend = backend
         self.log_queries = log_queries
         self.r_blk = r_blk
-        self.cache = DeviceSegmentCache(byte_budget=byte_budget)
+        self.cache = DeviceSegmentCache(byte_budget=byte_budget,
+                                        device=device)
         # optional core.batch_scan.ResultCache — NOT the segment cache
         # above: entries are whole per-query ScanResults under the same
         # (shard 0, clauses) keys and (epoch, data_version) validity the
@@ -114,6 +118,18 @@ class DeviceScanner:
                                          telemetry=False)
 
     # -- public API ---------------------------------------------------------
+
+    def plane_stats(self) -> dict:
+        """Residency counters of this scanner's segment plane."""
+        c, p = self.cache, self.cache.plane
+        return {
+            "device": None if p is None else str(*p.pres.devices()),
+            "slots": c.n_slots,
+            "plane_bytes": 0 if p is None else sum(a.nbytes for a in p),
+            "uploads": c.uploads,
+            "upload_bytes": c.upload_bytes,
+            "evictions": c.evictions,
+        }
 
     def scan(self, q: Query) -> ScanResult:
         return self.scan_batch([q])[0]
@@ -277,13 +293,17 @@ class DeviceScanner:
                 g.rows_skipped += seg.n_rows - cand
                 g.count += int(counts[qi, si])
                 result.segments_scanned += 1
+                result.rows_on_device += seg.n_rows
+
+            def host(seg, g, pushed):
+                self._host._scan_segment(seg, q, pushed, g, result)
+                result.rows_on_host += seg.n_rows
 
             for seg in store.blocks:
                 g = result.group(seg.epoch, seg.tier)
                 si = slot_of.get(id(seg)) if use_device else None
                 if si is None:
-                    self._host._scan_segment(
-                        seg, q, pm[(seg.epoch, seg.n_covered)], g, result)
+                    host(seg, g, pm[(seg.epoch, seg.n_covered)])
                 else:
                     eat(seg, g, si)
             for key, n in prep.promoted[qi].items():
@@ -295,7 +315,7 @@ class DeviceScanner:
                     continue
                 si = slot_of.get(id(seg)) if use_device else None
                 if si is None:
-                    self._host._scan_segment(seg, q, (), g, result)
+                    host(seg, g, ())
                 else:
                     eat(seg, g, si)
             result.sort_groups()
@@ -340,51 +360,68 @@ def _pad_params(p: ScanParams, T: int, C: int, Q: int, S1: int,
     )
 
 
-def _pad_plane(pl: DevicePlaneArrays, K: int, N: int) -> DevicePlaneArrays:
-    if pl.pres.shape == (K, N):
-        return pl
-    return DevicePlaneArrays(
-        pres=_grow2(pl.pres, k=K, n=N, fill=0),
-        notn=_grow2(pl.notn, k=K, n=N, fill=0),
-        isb=_grow2(pl.isb, k=K, n=N, fill=0),
-        numv=_grow2(pl.numv, k=K, n=N, fill=0),
-        scod=_grow2(pl.scod, k=K, n=N, fill=-1),
-        rcod=_grow2(pl.rcod, k=K, n=N, fill=-1),
-        sid=_grow1(pl.sid, n=N, fill=-1),
-        cw=_grow1(pl.cw, n=N, fill=0),
-    )
+@functools.lru_cache(maxsize=16)
+def _spmd_program(mesh: Mesh, backend: str, r_blk: int):
+    """Jitted ``shard_map`` scan over a ``("shards",)`` mesh: device i
+    runs the fused scan on block i of every argument (its own shard's
+    plane and parameter tables) and returns its ``(Q, S1)`` counts."""
+    if backend == "xla":
+        def body(*args):
+            return scan_core_xla(*args)
+    else:
+        def body(*args):
+            return _scan_core_pallas(
+                *args, r_blk=min(r_blk, args[0].shape[1]),
+                interpret=backend == "pallas_interpret")
+    spec = P("shards")
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                 out_specs=(spec, spec), check_vma=False))
 
 
-def _spmd_counts(planes: list[DevicePlaneArrays],
-                 params: list[ScanParams], mesh) -> list[tuple]:
-    """One ``shard_map`` program: shard i of the stacked inputs lands on
-    device i and runs the fused scan over its own plane."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+def _spmd_counts(caches: list[DeviceSegmentCache],
+                 params: list[ScanParams], backend: str,
+                 r_blk: int) -> list[tuple]:
+    """One SPMD launch over per-device shard planes.
 
-    K = max(pl.pres.shape[0] for pl in planes)
-    N = max(pl.pres.shape[1] for pl in planes)
+    Each cache's plane already lives on its own device; the caches are
+    grown once to common ``(K, N)`` buckets (device-side, persistent, so
+    steady-state scans grow nothing) and the global arrays are assembled
+    from those per-device buffers without moving them.  Parameter
+    tables are padded to common buckets and laid out back to back, so
+    block i of every argument is shard i's.
+    """
+    K = max(c.plane.pres.shape[0] for c in caches)
+    N = max(c.plane.pres.shape[1] for c in caches)
+    for c in caches:
+        c.ensure_capacity(K, N)
     T = max(p.kinds.shape[0] for p in params)
     C = max(p.membership.shape[0] for p in params)
     Q = max(p.query_clause.shape[0] for p in params)
     S1 = max(p.pushed_tbl.shape[1] for p in params)
     L = max(p.lut_flat.shape[0] for p in params)
-    planes = [_pad_plane(pl, K, N) for pl in planes]
     params = [_pad_params(p, T, C, Q, S1, L) for p in params]
-    stacked_plane = [jnp.stack(x) for x in zip(*planes)]
-    stacked_params = [np.stack(x) for x in zip(*params)]
-    spec = P("shards")
+    if backend == "xla":
+        tables = params
+    else:
+        ts = max(bucket_pow2(int((p.kinds == KIND_SUBSTRING).sum()))
+                 for p in params)
+        tables = [pallas_scan_args(p, K, n_sub=ts) for p in params]
+    mesh = Mesh(np.asarray([c.device for c in caches]), ("shards",))
+    sharding = NamedSharding(mesh, P("shards"))
+    n = len(caches)
 
-    def one(*args):
-        c, d = scan_core_xla(*(a[0] for a in args))
-        return c[None], d[None]
+    def global_array(bufs):
+        b = bufs[0]
+        return jax.make_array_from_single_device_arrays(
+            (n * b.shape[0],) + b.shape[1:], sharding, list(bufs))
 
-    run = shard_map(one, mesh=mesh,
-                    in_specs=tuple(spec for _ in range(20)),
-                    out_specs=(spec, spec))
-    counts, cands = jax.jit(run)(*stacked_plane, *stacked_params)
-    counts, cands = np.asarray(counts), np.asarray(cands)
-    return [(counts[i], cands[i]) for i in range(len(planes))]
+    plane = [global_array(x) for x in zip(*(c.plane for c in caches))]
+    args = [jax.device_put(np.concatenate(x), sharding)
+            for x in zip(*tables)]
+    counts, cands = _spmd_program(mesh, backend, r_blk)(*plane, *args)
+    counts = np.asarray(counts).reshape(n, Q, S1)
+    cands = np.asarray(cands).reshape(n, Q, S1)
+    return [(counts[i], cands[i]) for i in range(n)]
 
 
 class ShardedDeviceScanner:
@@ -397,7 +434,7 @@ class ShardedDeviceScanner:
     deterministically through ``merge_scan_results``.
     """
 
-    def __init__(self, store: ShardedCiaoStore, *, backend: str = "xla",
+    def __init__(self, store: ShardedCiaoStore, *, backend: str = "pallas",
                  byte_budget: int = 256 << 20, log_queries: bool = True,
                  r_blk: int = 512, spmd: bool | None = None,
                  telemetry: "object | bool | None" = None,
@@ -410,17 +447,29 @@ class ShardedDeviceScanner:
         self.telemetry = telemetry if isinstance(telemetry, TelemetryPlane) \
             else None
         self.tenant = tenant
+        # shard s's plane lives on device s (round-robin when shards
+        # outnumber devices)
+        devices = jax.devices()
         self._scanners = [
             DeviceScanner(s, backend=backend, byte_budget=byte_budget,
-                          log_queries=False, r_blk=r_blk, telemetry=False)
-            for s in store.shards
+                          log_queries=False, r_blk=r_blk, telemetry=False,
+                          device=devices[i % len(devices)])
+            for i, s in enumerate(store.shards)
         ]
-        # None = auto: engage iff a ("shards",) mesh fits the device count
-        self.spmd = spmd
+        self.backend = backend
+        self.r_blk = r_blk
+        # one SPMD launch whenever >= 2 shards launch, every shard owns a
+        # distinct device and the caller did not ask for spmd=False
+        self.spmd = spmd is not False and store.n_shards <= len(devices)
 
     @property
     def caches(self) -> list[DeviceSegmentCache]:
         return [sc.cache for sc in self._scanners]
+
+    def plane_stats(self) -> dict:
+        """Per-shard residency counters (see ``DeviceScanner``)."""
+        return {"spmd": self.spmd,
+                "shards": [sc.plane_stats() for sc in self._scanners]}
 
     def scan(self, q: Query) -> ScanResult:
         return self.scan_batch([q])[0]
@@ -476,15 +525,12 @@ class ShardedDeviceScanner:
                     jit_vis=jit_vis[s])
         launch = {s: p for s, p in prepared.items() if p.params is not None}
         outputs: dict[int, tuple] = {}
-        mesh = None
-        if self.spmd is not False and len(launch) >= 2:
-            mesh = scan_mesh(len(launch))
-        if mesh is not None and all(
-                sc.backend == "xla" for sc in self._scanners):
+        if self.spmd and len(launch) >= 2 and self.backend != "numpy":
             order = sorted(launch)
             per = _spmd_counts(
-                [self._scanners[s].cache.plane for s in order],
-                [launch[s].params for s in order], mesh)
+                [self._scanners[s].cache for s in order],
+                [launch[s].params for s in order], self.backend,
+                self.r_blk)
             outputs = dict(zip(order, per))
         else:
             for s, p in launch.items():

@@ -1264,6 +1264,11 @@ class ScanResult:
     # metadata refuted the query (first-level skipping) vs shards scanned
     shards_scanned: int = 0
     shards_pruned: int = 0
+    # device scanners only (DESIGN.md §15): rows answered on the device
+    # plane vs rows handed to their host fallback.  Where a row was
+    # evaluated is not part of the result, so equality ignores both.
+    rows_on_device: int = field(default=0, compare=False)
+    rows_on_host: int = field(default=0, compare=False)
 
     def group(self, epoch: int, tier: int) -> TierScan:
         return self.groups.setdefault((epoch, tier), TierScan())

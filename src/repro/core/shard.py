@@ -1389,6 +1389,8 @@ def merge_scan_results(results: Sequence[ScanResult]) -> ScanResult:
             segments_scanned=a.segments_scanned + b.segments_scanned,
             shards_scanned=a.shards_scanned + b.shards_scanned,
             shards_pruned=a.shards_pruned + b.shards_pruned,
+            rows_on_device=a.rows_on_device + b.rows_on_device,
+            rows_on_host=a.rows_on_host + b.rows_on_host,
         )
 
     # seed with a neutral element: the reduction then always allocates a

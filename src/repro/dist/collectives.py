@@ -95,12 +95,11 @@ def compressed_allreduce(tree: Any, mesh, axis: str = "pod") -> Any:
     ``n_axis * scale / 2``.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda t: jax.tree.map(lambda x: _quantized_psum(x, axis), t),
-        mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)
     return f(tree)
 
 

@@ -10,8 +10,7 @@ this module maps them onto mesh axes.  The contract:
   * with no active mesh every helper degrades to a no-op / replication,
     so single-device code paths never pay a constraint.
 
-Works across jax versions: ``current_mesh`` prefers the new global-mesh API
-(``jax.set_mesh``) and falls back to the legacy ``thread_resources`` env.
+``current_mesh`` reads the global mesh that ``jax.set_mesh`` installs.
 """
 from __future__ import annotations
 
@@ -60,57 +59,14 @@ def rules_for(profile: str) -> Mapping[str, tuple[str, ...]]:
     return _RULES[profile]
 
 
-def current_mesh() -> Mesh | None:
-    """The active mesh, or None — tolerant of old/new jax global-mesh APIs."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            m = get_abstract()
-            if m is not None and not m.empty:
-                return m
-        except Exception:
-            pass
-    try:  # legacy `with mesh:` context
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` — new or legacy jax API."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # legacy: Mesh itself is the context manager
+def current_mesh():
+    """The mesh set by ``jax.set_mesh`` (an ``AbstractMesh``), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _mesh_sizes(mesh: Mesh) -> dict[str, int]:
     return dict(mesh.shape)
-
-
-def scan_mesh(n_shards: int) -> Mesh | None:
-    """1-D mesh mapping store shards onto local devices, or ``None``.
-
-    The device scan plane (DESIGN.md §15) runs its scatter-gather scan
-    as ONE ``shard_map`` program when every shard can own a device;
-    otherwise callers fall back to sequential per-shard launches (the
-    results are bit-identical — the SPMD path only changes scheduling).
-    Requires >= 2 shards to be worth a mesh and >= ``n_shards`` devices
-    for the 1:1 placement.
-    """
-    if n_shards < 2:
-        return None
-    devs = jax.devices()
-    if len(devs) < n_shards:
-        return None
-    import numpy as _np
-
-    return Mesh(_np.asarray(devs[:n_shards]), ("shards",))
 
 
 def spec_for_leaf(shape: Sequence[int], axes: Sequence[str | None], mesh: Mesh,

@@ -24,10 +24,7 @@ from .plan import CompiledPlan, compile_plan, tier_view  # noqa: F401 (re-export
 
 
 class KernelEngine:
-    def __init__(self, backend: str = "pallas_interpret", r_blk: int = 256):
-        if backend == "pallas":
-            # explicit opt-in for real hardware; default harness is CPU
-            pass
+    def __init__(self, backend: str = "pallas", r_blk: int = 256):
         self.backend = backend
         self.r_blk = r_blk
         self.name = backend

@@ -34,7 +34,7 @@ def _pad_rows(data: np.ndarray, r_blk: int) -> tuple[jnp.ndarray, int]:
     return jnp.asarray(data), R
 
 
-def match_any(data, patterns, plens, *, backend: str = "pallas_interpret",
+def match_any(data, patterns, plens, *, backend: str = "pallas",
               r_blk: int = 256) -> np.ndarray:
     """bool[P, R] any-position multi-pattern match."""
     if backend == "xla":
@@ -56,7 +56,7 @@ def match_any(data, patterns, plens, *, backend: str = "pallas_interpret",
 
 
 def match_key_value(data, key: bytes, val: bytes, *,
-                    backend: str = "pallas_interpret", r_blk: int = 256) -> np.ndarray:
+                    backend: str = "pallas", r_blk: int = 256) -> np.ndarray:
     """bool[R] key-value predicate match (paper Table I row 4)."""
     mk, mv = len(key), len(val)
     unbounded = b"," in val or b"}" in val
@@ -78,7 +78,7 @@ def match_key_value(data, key: bytes, val: bytes, *,
     return np.asarray(out[0], dtype=bool)[:R]
 
 
-def clause_bitvectors(data, plan, *, backend: str = "pallas_interpret",
+def clause_bitvectors(data, plan, *, backend: str = "pallas",
                       r_blk: int = 256):
     """Fused pushdown pass: dense chunk -> packed per-clause bitvectors.
 
@@ -99,8 +99,6 @@ def clause_bitvectors(data, plan, *, backend: str = "pallas_interpret",
         raise ValueError("predicates must be ordered simple-first "
                          "(kernels.plan.compile_plan does this)")
     n_valid = jnp.asarray(np.array([[R]], dtype=np.int32))
-    to_col = lambda a: jnp.asarray(  # noqa: E731
-        np.asarray(a, dtype=np.int32).reshape(-1, 1))
 
     if backend == "xla":
         words, or_words, counts = ref.clause_bitvectors_ref(
@@ -116,11 +114,11 @@ def clause_bitvectors(data, plan, *, backend: str = "pallas_interpret",
     if backend not in _PALLAS_BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
     dataj, R = _pad_rows(data, r_blk)
+    i32 = lambda a: jnp.asarray(np.asarray(a, dtype=np.int32))  # noqa: E731
     words, or_words, counts = clause_bitvectors_fused(
-        dataj, jnp.asarray(plan.keys), to_col(plan.klens),
-        jnp.asarray(plan.vals), to_col(plan.vlens), to_col(plan.kinds),
-        to_col(plan.unbounded),
-        jnp.asarray(plan.membership, dtype=jnp.uint8), n_valid,
+        dataj, i32(plan.keys), i32(plan.klens), i32(plan.vals),
+        i32(plan.vlens), i32(plan.kinds), i32(plan.unbounded),
+        i32(plan.membership), i32([R]),
         r_blk=r_blk, interpret=(backend == "pallas_interpret"),
     )
     W = (R + 31) // 32
@@ -128,7 +126,7 @@ def clause_bitvectors(data, plan, *, backend: str = "pallas_interpret",
             np.asarray(counts))
 
 
-def reduce_bitvectors(bitvecs, *, backend: str = "pallas_interpret",
+def reduce_bitvectors(bitvecs, *, backend: str = "pallas",
                       w_blk: int = 128):
     """(and_words, or_words, surviving_count) over uint32[P, W]."""
     bv = np.asarray(bitvecs, dtype=np.uint32)

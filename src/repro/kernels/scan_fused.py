@@ -45,9 +45,13 @@ an exact integer form:
 Two backends: ``"xla"`` (jitted jnp, the fast path on CPU hosts) and
 ``"pallas"``/interpret (one real ``pallas_call``: per-row slot one-hot,
 parameter gathers and the final per-slot popcount are all expressed as
-f32 matmuls — exact for dictionary codes < 2^24 — so the kernel maps
-onto the MXU; the substring LUT probe is the one vector gather,
-supported by interpret mode and recent Mosaic toolchains).  Both return
+f32 matmuls — integer tables at fp32 contraction precision, exact for
+codes < 2^24 — so the kernel maps onto the MXU; plane masks arrive as
+uint8 and widen through int32, since Mosaic has no uint8 -> f32 cast.
+The substring LUT probe is a data-dependent gather into a LUT of
+unbounded length, which Mosaic cannot lower: it runs as 1-D XLA gathers
+in the same jitted program, one row per substring term, ahead of the
+kernel).  Both return
 ``counts[Q, S]`` / ``cands[Q, S]`` (matches, pushed-candidate rows) per
 cache slot, which the device scanner folds into the standard per-(epoch,
 tier) :class:`~repro.core.server.ScanResult` accounting.
@@ -305,11 +309,26 @@ _scan_core_xla = jax.jit(scan_core_xla, static_argnames=("kind_counts",))
 # pallas backend
 # ---------------------------------------------------------------------------
 
+def _mm(a, b, *, exact: bool = False):
+    """f32 matmul ``a @ b``.  ``exact`` forces fp32 contraction: one-hot
+    row selection of an integer table is then exact for |values| < 2^24
+    (a single-pass bf16 MXU product would round codes above 256)."""
+    return jax.lax.dot(
+        a, b, precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32)
+
+
+def _mm_t(a, b):
+    """f32 ``a @ b.T`` (0/1 operands, integer sums < 2^24: exact)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
 def _scan_kernel(keym_ref, pres_ref, notn_ref, isb_ref, numv_ref,
-                 scod_ref, rcod_ref, sid_ref, cw_ref,
-                 kinds_ref, code_a_ref, num_codes_ref, lut_off_ref,
-                 lut_flat_ref, is_null_ref, is_boolv_ref,
-                 mem_ref, qc_ref, plo_ref, phi_ref, act_ref,
+                 scod_ref, rcod_ref, sid_ref, cw_ref, sub_ref,
+                 subsel_ref, kinds_ref, code_a_ref, num_codes_ref,
+                 is_null_ref, is_boolv_ref, mem_ref, qc_ref,
+                 plo_ref, phi_ref, act_ref,
                  counts_ref, cands_ref, *, n_slots: int, r_blk: int):
     nb = pl.program_id(0)
 
@@ -318,70 +337,113 @@ def _scan_kernel(keym_ref, pres_ref, notn_ref, isb_ref, numv_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         cands_ref[...] = jnp.zeros_like(cands_ref)
 
-    f32 = jnp.float32
-    sid = sid_ref[0, :]
+    f32, i32 = jnp.float32, jnp.int32
+
+    def mask(ref):   # uint8 plane rows -> f32 0/1 (no direct u8->f32 cast)
+        return ref[...].astype(i32).astype(f32)
+
+    sid = sid_ref[...]                                    # (1, blk)
     sid = jnp.where(sid < 0, n_slots - 1, sid)
     # per-row slot one-hot: every parameter gather and the final per-slot
-    # reduction become (.., S1) x (S1, blk) matmuls — MXU-friendly, and
-    # exact in f32 for dictionary codes / offsets < 2^24
-    iota = jax.lax.broadcasted_iota(jnp.int32, (n_slots, r_blk), 0)
-    slot_oh = (sid[None, :] == iota).astype(f32)          # (S1, blk)
+    # reduction become (.., S1) x (S1, blk) matmuls on the MXU
+    iota = jax.lax.broadcasted_iota(i32, (n_slots, r_blk), 0)
+    slot_oh = (sid == iota).astype(f32)                   # (S1, blk)
+
+    def per_row(ref):  # (.., S1) integer table -> (.., blk), exact
+        return _mm(ref[...], slot_oh, exact=True).astype(i32)
+
     keym = keym_ref[...]                                  # (T, K) one-hot
-    tp = keym @ pres_ref[...].astype(f32) > 0.0           # (T, blk)
-    tn = keym @ notn_ref[...].astype(f32) > 0.0
-    tb = keym @ isb_ref[...].astype(f32) > 0.0
-    tv = keym @ numv_ref[...].astype(f32) > 0.0
-    ts = (keym @ scod_ref[...].astype(f32)).astype(jnp.int32)
-    tr = (keym @ rcod_ref[...].astype(f32)).astype(jnp.int32)
-    ca = (code_a_ref[...].astype(f32) @ slot_oh).astype(jnp.int32)
-    off = (lut_off_ref[...].astype(f32) @ slot_oh).astype(jnp.int32)
+    tp = _mm(keym, mask(pres_ref)) > 0.0                  # (T, blk)
+    tn = _mm(keym, mask(notn_ref)) > 0.0
+    tb = _mm(keym, mask(isb_ref)) > 0.0
+    tv = _mm(keym, mask(numv_ref)) > 0.0
+    ts = _mm(keym, scod_ref[...].astype(f32), exact=True).astype(i32)
+    tr = _mm(keym, rcod_ref[...].astype(f32), exact=True).astype(i32)
+    ca = per_row(code_a_ref)
     m_exact = ts == ca
-    lut = lut_flat_ref[0, :]
-    idx = jnp.clip(off + 1 + ts, 0, lut.shape[0] - 1)
-    m_sub = (jnp.take(lut, idx) > 0) & (off >= 0)
-    ncf = (num_codes_ref[...].astype(f32) @ slot_oh).astype(jnp.int32)
-    nc = ncf.reshape(-1, 3, r_blk)                        # (T, 3, blk)
-    m_num = tv & jnp.any(nc == tr[:, None, :], axis=1)
-    isn = is_null_ref[...] > 0                            # (T, 1)
-    isb_v = is_boolv_ref[...] > 0
-    m_null = isn & tp & ~tn
-    compat = jnp.where(isb_v, tb, tp & ~tb)
+    # substring LUT probes arrive pre-gathered (one row per substring
+    # term); subsel maps them back onto their term rows
+    m_sub = _mm(subsel_ref[...], sub_ref[...].astype(f32)) > 0.0
+    m_num = tv & ((per_row(num_codes_ref.at[0]) == tr)
+                  | (per_row(num_codes_ref.at[1]) == tr)
+                  | (per_row(num_codes_ref.at[2]) == tr))
+    shape = tp.shape
+
+    def flag(ref):   # (T, 1) int32 term flag -> (T, blk) bool
+        return jnp.broadcast_to(ref[...], shape) > 0
+
+    m_null = flag(is_null_ref) & tp & ~tn
+    boolv = flag(is_boolv_ref)
+    compat = (boolv & tb) | (~boolv & tp & ~tb)      # no i1 select
     m_kv = ((tr == ca) | m_num | m_null) & compat
-    k = kinds_ref[...]                                    # (T, 1)
-    term = jnp.where(
-        k == KIND_PRESENCE, tn,
-        jnp.where(k == KIND_EXACT, m_exact,
-                  jnp.where(k == KIND_SUBSTRING, m_sub,
-                            jnp.where(k == KIND_KV, m_kv, False))))
-    cm = (mem_ref[...].astype(f32) @ term.astype(f32)) > 0.0   # (C, blk)
-    viol = qc_ref[...].astype(f32) @ (1.0 - cm.astype(f32))
+    k = jnp.broadcast_to(kinds_ref[...], shape)
+    term = (((k == KIND_PRESENCE) & tn) | ((k == KIND_EXACT) & m_exact)
+            | ((k == KIND_SUBSTRING) & m_sub) | ((k == KIND_KV) & m_kv))
+    cm = _mm(mem_ref[...], term.astype(f32)) > 0.0         # (C, blk)
+    viol = _mm(qc_ref[...], 1.0 - cm.astype(f32))
     qm = viol == 0.0                                      # (Q, blk)
-    # pushed words gathered as two exact 16-bit f32 halves
-    plo = (plo_ref[...].astype(f32) @ slot_oh).astype(jnp.uint32)
-    phi = (phi_ref[...].astype(f32) @ slot_oh).astype(jnp.uint32)
-    ptab = (phi << 16) | plo
-    cw = cw_ref[0, :]
-    pm = (cw[None, :] & ptab) == ptab
-    act = (act_ref[...].astype(f32) @ slot_oh) > 0.0
+    # pushed words gathered as two exact 16-bit halves (int32 bit ops)
+    ptab = (per_row(phi_ref) << 16) | per_row(plo_ref)
+    cw = cw_ref[...]                                      # (1, blk) int32
+    pm = (cw & ptab) == ptab
+    act = _mm(act_ref[...], slot_oh) > 0.0
     hit = (qm & pm & act).astype(f32)
-    counts_ref[...] += hit @ slot_oh.T                    # (Q, S1)
-    cands_ref[...] += (pm & act).astype(f32) @ slot_oh.T
+    counts_ref[...] += _mm_t(hit, slot_oh)                # (Q, S1)
+    cands_ref[...] += _mm_t((pm & act).astype(f32), slot_oh)
+
+
+def _substring_hits(scod, sid, sub_keys, sub_off, lut_flat):
+    """``int32[Ts, N]`` substring LUT probes (XLA gathers).
+
+    Row j probes term j's per-slot LUT with the row's string code —
+    exactly the ``m_sub`` of :func:`scan_core_xla`.  A data-dependent
+    gather into a LUT of unbounded length has no Mosaic lowering, so it
+    runs as an XLA stage of the same jitted program, ahead of the kernel.
+    """
+    S1 = sub_off.shape[1]
+    L = lut_flat.shape[0]
+    sid = jnp.where(sid < 0, S1 - 1, sid)
+    rows = []
+    for j in range(sub_keys.shape[0]):    # Ts is a small static bucket
+        # one row at a time keeps every gather 1-D: a 2-D (Ts, N)
+        # gather picks a lane-padded (N, Ts) layout on TPU (~1 GB temp)
+        off = sub_off[j][sid]                              # (N,)
+        code = jax.lax.dynamic_index_in_dim(
+            scod, sub_keys[j], axis=0, keepdims=False)     # (N,)
+        idx = jnp.clip(off + 1 + code, 0, L - 1)
+        rows.append((lut_flat[idx] > 0) & (off >= 0))
+    return jnp.stack(rows).astype(jnp.int32)
 
 
 @functools.partial(
     jax.jit, static_argnames=("r_blk", "interpret"))
 def _scan_core_pallas(pres, notn, isb, numv, scod, rcod, sid, cw,
-                      keym, kinds, code_a, num_codes, lut_off, lut_flat,
-                      is_null, is_boolv, membership, query_clause,
-                      plo, phi, active, *, r_blk: int, interpret: bool):
+                      keym, kinds, code_a, num_codes, sub_keys, sub_off,
+                      subsel, lut_flat, is_null, is_boolv, membership,
+                      query_clause, plo, phi, active, *, r_blk: int,
+                      interpret: bool):
+    """Fused scan: XLA substring probes + one ``pallas_call``.
+
+    Plane: ``uint8[K, N]`` masks, ``int32[K, N]`` codes, ``int32[N]``
+    ``sid`` / ``cw`` (clause words bit-cast to int32).  Parameters:
+    f32 one-hot / 0-1 tables (``keym [T, K]``, ``subsel [T, Ts]``,
+    ``membership [C, T]``, ``query_clause [Q, C]``, ``active [Q, S1]``),
+    f32 integer tables (``code_a [T, S1]``, ``num_codes [3, T, S1]``,
+    ``plo`` / ``phi [Q, S1]``), ``int32 [T, 1]`` term flags, and the
+    substring LUT inputs (``sub_keys int32[Ts]``, ``sub_off
+    int32[Ts, S1]``, ``lut_flat uint8[L]``).
+    """
     K, N = pres.shape
     T = kinds.shape[0]
     C = membership.shape[0]
     Q, S1 = plo.shape
-    L = lut_flat.shape[1]
-    grid = (N // r_blk,)
+    Ts = subsel.shape[1]
+    if N % r_blk or r_blk % 128:
+        raise ValueError(f"N={N} not a multiple of r_blk={r_blk} "
+                         f"(a multiple of 128)")
+    sub = _substring_hits(scod, sid, sub_keys, sub_off, lut_flat)
 
-    def col(k):      # (K, N) column tiles
+    def col(k):      # (k, N) column tiles
         return pl.BlockSpec((k, r_blk), lambda nb: (0, nb))
 
     def full(*shape):  # whole-array parameter blocks
@@ -389,35 +451,63 @@ def _scan_core_pallas(pres, notn, isb, numv, scod, rcod, sid, cw,
 
     counts, cands = pl.pallas_call(
         functools.partial(_scan_kernel, n_slots=S1, r_blk=r_blk),
-        grid=grid,
+        grid=(N // r_blk,),
         in_specs=[
             full(T, K),                       # keym
             col(K), col(K), col(K), col(K),   # pres/notn/isb/numv
             col(K), col(K),                   # scod/rcod
             col(1), col(1),                   # sid/cw
+            col(Ts),                          # substring probes
+            full(T, Ts),                      # subsel
             full(T, 1),                       # kinds
             full(T, S1),                      # code_a
-            full(3 * T, S1),                  # num_codes
-            full(T, S1),                      # lut_off
-            full(1, L),                       # lut_flat
+            full(3, T, S1),                   # num_codes
             full(T, 1), full(T, 1),           # is_null / is_boolv
             full(C, T), full(Q, C),           # membership / query_clause
             full(Q, S1), full(Q, S1),         # plo / phi
             full(Q, S1),                      # active
         ],
-        out_specs=[
-            pl.BlockSpec((Q, S1), lambda nb: (0, 0)),
-            pl.BlockSpec((Q, S1), lambda nb: (0, 0)),
-        ],
+        out_specs=[full(Q, S1), full(Q, S1)],
         out_shape=[
             jax.ShapeDtypeStruct((Q, S1), jnp.float32),
             jax.ShapeDtypeStruct((Q, S1), jnp.float32),
         ],
         interpret=interpret,
-    )(keym, pres, notn, isb, numv, scod, rcod, sid, cw, kinds,
-      code_a, num_codes, lut_off, lut_flat, is_null, is_boolv,
-      membership, query_clause, plo, phi, active)
+    )(keym, pres, notn, isb, numv, scod, rcod,
+      sid.reshape(1, N), jax.lax.bitcast_convert_type(cw, jnp.int32)
+      .reshape(1, N), sub, subsel, kinds, code_a, num_codes,
+      is_null, is_boolv, membership, query_clause, plo, phi, active)
     return counts.astype(jnp.int32), cands.astype(jnp.int32)
+
+
+def pallas_scan_args(params: ScanParams, n_keys: int,
+                     n_sub: int | None = None) -> tuple:
+    """Host-side parameter tables of :func:`_scan_core_pallas`, in its
+    argument order after the eight plane arrays.  ``n_sub`` pads the
+    substring-probe rows to a common bucket (SPMD launches)."""
+    T = params.kinds.shape[0]
+    f32 = np.float32
+    keym = np.zeros((T, n_keys), f32)
+    keym[np.arange(T), params.key_ids] = 1.0
+    sub_rows = np.flatnonzero(params.kinds == KIND_SUBSTRING)
+    Ts = n_sub or bucket_pow2(len(sub_rows))
+    subsel = np.zeros((T, Ts), f32)
+    subsel[sub_rows, np.arange(len(sub_rows))] = 1.0
+    sub_keys = np.zeros((Ts,), np.int32)
+    sub_keys[:len(sub_rows)] = params.key_ids[sub_rows]
+    sub_off = np.full((Ts, params.lut_off.shape[1]), -1, np.int32)
+    sub_off[:len(sub_rows)] = params.lut_off[sub_rows]
+    col = lambda a: a.astype(np.int32).reshape(-1, 1)  # noqa: E731
+    return (
+        keym, col(params.kinds), params.code_a.astype(f32),
+        params.num_codes.transpose(1, 0, 2).astype(f32),
+        sub_keys, sub_off, subsel, params.lut_flat,
+        col(params.is_null), col(params.is_boolv),
+        params.membership.astype(f32), params.query_clause.astype(f32),
+        (params.pushed_tbl & np.uint32(0xFFFF)).astype(f32),
+        (params.pushed_tbl >> np.uint32(16)).astype(f32),
+        params.active.astype(f32),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -492,7 +582,7 @@ def scan_core_numpy(pres, notn, isb, numv, scod, rcod, sid, cw,
 
 
 def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
-                backend: str = "xla", r_blk: int = 512,
+                backend: str = "pallas", r_blk: int = 512,
                 ) -> tuple[np.ndarray, np.ndarray]:
     """One fused launch over the plane; ``(counts, cands)`` as int32[Q, S1].
 
@@ -533,32 +623,11 @@ def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
             kind_counts=kc,
         )
     elif backend in ("pallas", "pallas_interpret"):
-        K = plane.pres.shape[0]
-        T = params.kinds.shape[0]
-        keym = np.zeros((T, K), np.float32)
-        keym[np.arange(T), params.key_ids] = 1.0
-        n = plane.sid.shape[0]
-        r_blk = min(r_blk, n)
+        args = pallas_scan_args(params, plane.pres.shape[0])
         counts, cands = _scan_core_pallas(
-            plane.pres, plane.notn, plane.isb, plane.numv,
-            plane.scod, plane.rcod,
-            plane.sid.reshape(1, -1), plane.cw.reshape(1, -1),
-            jnp.asarray(keym),
-            jnp.asarray(params.kinds.reshape(-1, 1)),
-            jnp.asarray(params.code_a),
-            jnp.asarray(params.num_codes.reshape(
-                params.num_codes.shape[0] * 3, -1)),
-            jnp.asarray(params.lut_off),
-            jnp.asarray(params.lut_flat.reshape(1, -1)),
-            jnp.asarray(params.is_null.reshape(-1, 1)),
-            jnp.asarray(params.is_boolv.reshape(-1, 1)),
-            jnp.asarray(params.membership), jnp.asarray(params.query_clause),
-            jnp.asarray((params.pushed_tbl & np.uint32(0xFFFF))
-                        .astype(np.int32)),
-            jnp.asarray((params.pushed_tbl >> np.uint32(16))
-                        .astype(np.int32)),
-            jnp.asarray(params.active),
-            r_blk=r_blk, interpret=(backend == "pallas_interpret"),
+            *plane, *(jnp.asarray(a) for a in args),
+            r_blk=min(r_blk, plane.sid.shape[0]),
+            interpret=(backend == "pallas_interpret"),
         )
     else:
         raise ValueError(f"unknown device scan backend {backend!r}")

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro._compat import jaxapi as jax_compat
 from repro.analysis import flops as flops_mod
 from repro.analysis import hlo as hlo_mod
 from repro.analysis import roofline as rl
@@ -79,7 +78,7 @@ def build_lowered(arch: str, shape_name: str, mesh, *, overrides=None):
         "active_params": model.active_param_count(),
     }
 
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = opt_config_for(cfg)
             opt_sds = jax.eval_shape(lambda p: opt_mod.init(p, opt_cfg), values_sds)
@@ -170,7 +169,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = jax_compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = hlo_mod.collective_bytes(hlo)   # trip-count-scaled (analysis.hlo)
     if hlo_dir:
@@ -193,6 +192,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         collective_bytes=float(coll["total"]),
         model_flops_global=mf,
         n_devices=n_dev,
+        device_kind=rl.V5E,
         collectives={
             "bytes": coll["bytes"],
             "counts": coll["counts"],

@@ -14,9 +14,11 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro._compat import jaxapi as _compat
+
+def _mesh(dev_array, axes) -> Mesh:
+    return Mesh(dev_array, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -32,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             "jax import)"
         )
     dev_array = np.array(devices[:need]).reshape(shape)
-    return _compat.make_mesh(dev_array, axes)
+    return _mesh(dev_array, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
@@ -41,4 +43,4 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     devices = jax.devices()
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices, have {len(devices)}")
-    return _compat.make_mesh(np.array(devices[:need]).reshape(shape), axes)
+    return _mesh(np.array(devices[:need]).reshape(shape), axes)

@@ -150,7 +150,7 @@ def main(argv=None) -> dict:
     data_it = Prefetcher(batcher.batches(recipe, repeat=True), depth=2)
     losses = []
     t0 = time.time()
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             tokens, mask = next(data_it)
             batch = {"tokens": jnp.asarray(tokens), "loss_mask": jnp.asarray(mask)}

@@ -53,7 +53,6 @@ def apply_moe_sharded(p, x, cfg: ModelConfig):
     deepseek-v3-671b x train_4k; see EXPERIMENTS.md).
     """
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m: MoEConfig = cfg.moe
@@ -125,7 +124,7 @@ def apply_moe_sharded(p, x, cfg: ModelConfig):
         (P(None, "model"), P(None, "model"), P("model", None)) if shared_ok else None
     )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -137,7 +136,7 @@ def apply_moe_sharded(p, x, cfg: ModelConfig):
             shared_specs,
         ),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, p["router"], p["wi"], p["wg"], p["wo"], shared_in)
     if m.n_shared_experts and not shared_ok:

@@ -259,8 +259,10 @@ class CiaoServeEngine:
       * ``admission`` — optional :class:`QueryAdmission`.
       * ``result_cache`` — optional shared
         :class:`~repro.core.batch_scan.ResultCache` (thread-safe).
-      * ``device_backend`` — backend for ``mode="device"`` queries
-        (``"xla"``, ``"pallas_interpret"``, or ``"numpy"``).
+      * ``device_backend`` — backend for ``mode="device"`` queries: the
+        compiled scan kernel ``"pallas"`` unless the caller names
+        ``"xla"``, ``"pallas_interpret"`` (CPU) or ``"numpy"`` (host
+        reference).
     """
 
     def __init__(self, store: "CiaoStore | ShardedCiaoStore", *,
@@ -268,7 +270,7 @@ class CiaoServeEngine:
                  backpressure: str = "block",
                  admission: QueryAdmission | None = None,
                  result_cache: ResultCache | None = None,
-                 device_backend: str = "numpy",
+                 device_backend: str = "pallas",
                  eager_promote_uncovered: bool = True,
                  refresh_interval_s: float = 0.02,
                  log_queries: bool = True):
@@ -602,6 +604,9 @@ class CiaoServeEngine:
                 "errors": len(self._errors),
             }
         out = {"engine": eng, "store": self.store.stats_report()}
+        readers = self._readers
+        if readers is not None and readers._device is not None:
+            out["device_plane"] = readers._device.plane_stats()
         if self._tuner is not None:
             out["tuner"] = {
                 "migrating": bool(getattr(self._tuner, "migrating", False)),

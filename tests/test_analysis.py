@@ -75,9 +75,7 @@ def _flops_from_compiled(cfg, shape, kind="train"):
         def fn(params, inputs):
             return model.prefill(params, inputs, s_alloc=shape.seq_len + 8)
         compiled = jax.jit(fn).lower(values_sds, specs).compile()
-    from repro._compat.jaxapi import cost_analysis
-
-    return float(cost_analysis(compiled)["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 @pytest.mark.parametrize("arch", ["qwen3-8b", "deepseek-v3-671b", "recurrentgemma-9b"])
@@ -133,3 +131,19 @@ def test_dryrun_artifacts_complete():
             assert rec["roofline"]["device_flops"] > 0, f
             n_ok += 1
     assert n_ok == 64 and n_skip == 16, (n_ok, n_skip)
+
+
+def test_chip_peaks_table():
+    """Published v5e peaks keyed by device_kind; unknown kinds raise."""
+    from repro.analysis import roofline as rl
+
+    v5e = rl.chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.int8_ops, v5e.hbm_bw) == (197e12, 393e12, 819e9)
+    assert rl.chip_peaks(rl.V5E) is v5e
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.chip_peaks("TPU v4")
+    with pytest.raises(ValueError):
+        rl.Roofline(arch="a", shape="s", mesh="1x1", device_flops=1.0,
+                    device_bytes=1.0, collective_bytes=0.0,
+                    model_flops_global=1.0, n_devices=1,
+                    device_kind="cpu").finalize()

@@ -48,19 +48,14 @@ def test_jnp_parity():
 @given(st.integers(0, 2**31), st.integers(0, 400))
 @settings(max_examples=60, deadline=None)
 def test_popcount_fallback_matches(seed, r):
-    """numpy<2 path: the unpackbits fallback == np.bitwise_count path.
-
-    The fallback is what ``bv.popcount`` resolves to when
-    ``np.bitwise_count`` is unavailable; it must agree bit-for-bit with
-    the primary implementation and with the unpacked ground truth on
-    arbitrary shapes (including empty and non-contiguous inputs).
-    """
+    """``np.bitwise_count`` popcounts agree with the unpacked ground truth
+    on arbitrary shapes (including empty and non-contiguous inputs)."""
     rng = np.random.default_rng(seed)
     bits = rng.random(r) < 0.3
     words = bv.pack(bits)
     expected = int(bits.sum())
     assert bv.popcount(words) == expected
-    assert bv._popcount_unpack(words) == expected
-    # non-contiguous view (fallback must not assume contiguity)
+    # non-contiguous view: popcount must not assume contiguity
     two = np.stack([words, words])
-    assert bv._popcount_unpack(two.T) == 2 * expected
+    assert bv.popcount(two.T) == 2 * expected
+    assert bv.popcount_rows(two).tolist() == [expected, expected]

@@ -127,8 +127,9 @@ def test_batch_matches_one_at_a_time(ycsb):
                jit=False)
     b = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1,
                jit=False)
-    batched = DeviceScanner(a, log_queries=False).scan_batch(queries)
-    sc = DeviceScanner(b, log_queries=False)
+    batched = DeviceScanner(a, backend="xla",
+                            log_queries=False).scan_batch(queries)
+    sc = DeviceScanner(b, backend="xla", log_queries=False)
     singles = [sc.scan(q) for q in queries]
     for q, rb, rs in zip(queries, batched, singles):
         assert _accounting(rb) == _accounting(rs), q.describe()
@@ -144,7 +145,8 @@ def test_multi_query_batch_vs_host_with_raw_promotion(ycsb):
                jit=False)
     b = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1,
                jit=False)
-    dev_res = DeviceScanner(a, log_queries=False).scan_batch(queries)
+    dev_res = DeviceScanner(a, backend="xla",
+                            log_queries=False).scan_batch(queries)
     host = DataSkippingScanner(b, log_queries=False)
     for q, r in zip(queries, dev_res):
         assert _accounting(r) == _accounting(host.scan(q)), q.describe()
@@ -170,7 +172,7 @@ def test_sharded_device_bit_identical(ycsb, n_shards):
                                     segment_capacity=512),
                    recs, fam0, fam1)
     queries = _workload(fam0, fam1, ranked)
-    dev = ShardedDeviceScanner(s_dev, log_queries=False)
+    dev = ShardedDeviceScanner(s_dev, backend="xla", log_queries=False)
     got = dev.scan_batch(queries)
     with ShardedScanner(s_host, log_queries=False) as sc:
         for q, r in zip(queries, got):
@@ -192,7 +194,7 @@ def test_sharded_device_range_router_prunes_shards(ycsb):
                                     segment_capacity=512),
                    recs, fam0, fam1)
     queries = _workload(fam0, fam1, ranked)
-    dev = ShardedDeviceScanner(s_dev, log_queries=False)
+    dev = ShardedDeviceScanner(s_dev, backend="xla", log_queries=False)
     got = dev.scan_batch(queries)
     pruned = 0
     with ShardedScanner(s_host, log_queries=False) as sc:
@@ -210,7 +212,7 @@ def test_empty_store_and_all_pruned_segments(ycsb):
     recs, objs, ranked = ycsb
     fam0, fam1 = _families(ranked)
     empty = CiaoStore(fam0, segment_capacity=512)
-    dev = DeviceScanner(empty, log_queries=False)
+    dev = DeviceScanner(empty, backend="xla", log_queries=False)
     r = dev.scan(Query((ranked[0],)))
     assert (r.count, r.rows_scanned, r.rows_skipped) == (0, 0, 0)
     # populated store, query whose zone maps refute EVERY segment: the
@@ -218,7 +220,7 @@ def test_empty_store_and_all_pruned_segments(ycsb):
     # matches the host's all-pruned path
     store = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1)
     host = DataSkippingScanner(store, log_queries=False)
-    dev = DeviceScanner(store, log_queries=False)
+    dev = DeviceScanner(store, backend="xla", log_queries=False)
     q = Query((clause(key_value("linear_score", 250)),))
     got, want = dev.scan(q), host.scan(q)
     assert got.count == 0
@@ -255,7 +257,7 @@ def test_dictionary_strings_and_nan_zone_bounds():
         store.ingest_chunk(chunk, bv, epoch=0, tier=0)
     store.jit_load_raw()
     host = DataSkippingScanner(store, log_queries=False)
-    dev = DeviceScanner(store, log_queries=False)
+    dev = DeviceScanner(store, backend="xla", log_queries=False)
     queries = [
         Query((clause(exact("s", "par,is")),)),
         Query((clause(exact("s", "")),)),
@@ -285,7 +287,7 @@ def test_steady_state_zero_uploads_and_ingest_resync(ycsb):
     store = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1)
     twin = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1)
     host = DataSkippingScanner(twin, log_queries=False)
-    dev = DeviceScanner(store, log_queries=False)
+    dev = DeviceScanner(store, backend="xla", log_queries=False)
     queries = _workload(fam0, fam1, ranked)
     dev.scan_batch(queries)
     warm = dev.cache.uploads
@@ -311,7 +313,8 @@ def test_cache_eviction_mid_sweep_stays_bit_identical(ycsb):
     fam0, fam1 = _families(ranked)
     store = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1)
     host = DataSkippingScanner(store, log_queries=False)
-    dev = DeviceScanner(store, byte_budget=200 << 10, log_queries=False)
+    dev = DeviceScanner(store, backend="xla", byte_budget=200 << 10,
+                        log_queries=False)
     queries = _workload(fam0, fam1, ranked)
     for q in queries:                     # one at a time: LRU churns
         assert _accounting(dev.scan(q)) == _accounting(host.scan(q)), \
@@ -367,8 +370,9 @@ def test_residual_pow2_buckets_pin_trace_count():
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def test_spmd_shard_map_bit_identical_subprocess():
-    code = textwrap.dedent("""
+def _spmd_subprocess(backend: str, n_queries: int) -> dict:
+    """SPMD vs sequential sharded device scans on 4 host devices."""
+    code = textwrap.dedent(f"""
         import json
         import numpy as np
         import jax
@@ -376,6 +380,7 @@ def test_spmd_shard_map_bit_identical_subprocess():
         from tests.test_device_scan import (
             _accounting, _build, _families, _workload,
         )
+        from repro.core import device_scan
         from repro.core.device_scan import ShardedDeviceScanner
         from repro.core.server import CiaoStore, DataSkippingScanner
         from repro.core.shard import ShardedCiaoStore, ShardRouter
@@ -394,13 +399,22 @@ def test_spmd_shard_map_bit_identical_subprocess():
         s_seq = _build(ShardedCiaoStore(fam0, router=router,
                                         segment_capacity=512),
                        recs, fam0, fam1)
-        queries = _workload(fam0, fam1, ranked)[:8]
-        spmd = ShardedDeviceScanner(s_dev, log_queries=False, spmd=True)
-        seq = ShardedDeviceScanner(s_seq, log_queries=False, spmd=False)
+        queries = _workload(fam0, fam1, ranked)[:{n_queries}]
+        spmd = ShardedDeviceScanner(s_dev, backend="{backend}",
+                                    log_queries=False, spmd=True)
+        seq = ShardedDeviceScanner(s_seq, backend="{backend}",
+                                   log_queries=False, spmd=False)
         a = spmd.scan_batch(queries)
         b = seq.scan_batch(queries)
         same = all(_accounting(x) == _accounting(y) for x, y in zip(a, b))
-        print(json.dumps({"same": same, "n": len(a)}))
+        # each shard's plane stays on its own device
+        homes = [c.plane.pres.devices() for c in spmd.caches]
+        print(json.dumps({{
+            "same": same, "n": len(a),
+            "homes": len(set().union(*homes)),
+            "one_home_each": all(len(h) == 1 for h in homes),
+            "spmd_programs": device_scan._spmd_program.cache_info().misses,
+        }}))
     """)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -410,5 +424,18 @@ def test_spmd_shard_map_bit_identical_subprocess():
         env=env, timeout=900,
     )
     assert out.returncode == 0, out.stderr[-4000:]
-    payload = json.loads(out.stdout.strip().splitlines()[-1])
-    assert payload == {"same": True, "n": 8}
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_spmd_shard_map_bit_identical_subprocess():
+    payload = _spmd_subprocess("xla", 8)
+    assert payload == {"same": True, "n": 8, "homes": 4,
+                       "one_home_each": True, "spmd_programs": 1}
+
+
+def test_spmd_pallas_interpret_bit_identical_subprocess():
+    """The compiled-kernel backend's SPMD launch (interpreted here) over
+    per-device shard planes matches the sequential per-shard launches."""
+    payload = _spmd_subprocess("pallas_interpret", 4)
+    assert payload == {"same": True, "n": 4, "homes": 4,
+                       "one_home_each": True, "spmd_programs": 1}

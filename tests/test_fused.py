@@ -106,12 +106,13 @@ def test_multi_block_accumulation(backend):
     accumulate correctly across grid blocks (and the word slice drops the
     padding tile)."""
     rng = np.random.default_rng(5)
-    objs = [_random_record(rng) for _ in range(150)]
+    objs = [_random_record(rng) for _ in range(300)]
     recs = [json.dumps(o, separators=(",", ":")).encode() for o in objs]
     chunk = encode_chunk(recs)
     clauses = _random_clauses(rng, 5)
     expected = PythonEngine().eval_fused(chunk, clauses)
-    eng = KernelEngine(backend=backend, r_blk=64)  # 150 -> 3 tiles of 64
+    # record tiles are lane-dense: r_blk is a multiple of 128
+    eng = KernelEngine(backend=backend, r_blk=128)  # 300 -> 3 tiles of 128
     fused = eng.eval_fused(chunk, clauses)
     assert np.array_equal(fused.words, expected.words)
     assert np.array_equal(fused.or_words, expected.or_words)

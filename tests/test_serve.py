@@ -285,7 +285,7 @@ def test_engine_quiesced_counts_match_oracle(ycsb):
     queries = [Query(clauses=(pool[k],)) for k in range(6)]
     oracle = [_oracle(objs, q) for q in queries]
 
-    with CiaoServeEngine(store, queue_depth=8,
+    with CiaoServeEngine(store, queue_depth=8, device_backend="numpy",
                          result_cache=ResultCache()) as serve:
         for ch, bv, tier in chunks:
             serve.ingest_chunk(ch, bv, epoch=fam.plan.epoch, tier=tier)
@@ -473,7 +473,7 @@ def test_threaded_stress_sweep(ycsb):
     store = ShardedCiaoStore(fam, router=router, segment_capacity=256)
     queries = [Query(clauses=(pool[k],)) for k in range(8)]
     oracle = [_oracle(objs, q) for q in queries]
-    serve = CiaoServeEngine(store, queue_depth=4,
+    serve = CiaoServeEngine(store, queue_depth=4, device_backend="numpy",
                             result_cache=ResultCache())
     writers_done = threading.Event()
     errors: list[BaseException] = []
