@@ -23,6 +23,7 @@ import numpy as np
 
 from . import bitvector
 from .predicates import Clause, Kind, SimplePredicate
+from .telemetry import spanned
 
 
 # ---------------------------------------------------------------------------
@@ -54,6 +55,7 @@ class Chunk:
         return int(self.lengths.sum())
 
 
+@spanned("ciao.client.encode")
 def encode_chunk(records: Sequence[bytes], *, stride: int | None = None,
                  align: int = 128) -> Chunk:
     """Pad records into a dense uint8 matrix.

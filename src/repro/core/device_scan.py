@@ -57,6 +57,7 @@ from repro.core.device_cache import CacheSlot, DeviceSegmentCache
 from repro.core.predicates import Query
 from repro.core.server import CiaoStore, DataSkippingScanner, ScanResult
 from repro.core.shard import ShardedCiaoStore, merge_scan_results
+from repro.core.telemetry import span, spanned
 from repro.kernels.scan_fused import (
     KIND_SUBSTRING, ScanBatch, ScanParams, _scan_core_pallas, bucket_pow2,
     compile_scan_batch, pallas_scan_args, scan_core_numpy, scan_core_xla,
@@ -147,27 +148,28 @@ class DeviceScanner:
         t0 = time.perf_counter()
         store = self.store
         queries = tuple(queries)
-        if self.log_queries:
-            for q in queries:
-                store.log_query(q)
-        hits: dict[int, ScanResult] = {}
-        miss: list[int] = []
-        pushed_maps: list = []
-        promoted: list[dict] = []
-        jit_vis: list[int] = []
-        for qi, q in enumerate(queries):
-            if self.result_cache is not None:
-                r = self.result_cache.lookup(
-                    0, q, epoch=store.plan.epoch,
-                    data_version=store.data_version)
-                if r is not None:
-                    hits[qi] = r
-                    continue
-            pm = store.pushed_by_epoch(q)
-            pushed_maps.append(pm)
-            promoted.append(dict(store.promote_uncovered_raw(pm)))
-            jit_vis.append(len(store.jit_blocks))
-            miss.append(qi)
+        with span("ciao.scan.resolve"):
+            if self.log_queries:
+                for q in queries:
+                    store.log_query(q)
+            hits: dict[int, ScanResult] = {}
+            miss: list[int] = []
+            pushed_maps: list = []
+            promoted: list[dict] = []
+            jit_vis: list[int] = []
+            for qi, q in enumerate(queries):
+                if self.result_cache is not None:
+                    r = self.result_cache.lookup(
+                        0, q, epoch=store.plan.epoch,
+                        data_version=store.data_version)
+                    if r is not None:
+                        hits[qi] = r
+                        continue
+                pm = store.pushed_by_epoch(q)
+                pushed_maps.append(pm)
+                promoted.append(dict(store.promote_uncovered_raw(pm)))
+                jit_vis.append(len(store.jit_blocks))
+                miss.append(qi)
         by_pos: dict[int, ScanResult] = dict(hits)
         if miss:
             prep = self._prepare(
@@ -194,6 +196,7 @@ class DeviceScanner:
 
     # -- pipeline stages (ShardedDeviceScanner drives these directly) ------
 
+    @spanned("ciao.scan.prepare")
     def _prepare(self, queries: Sequence[Query], *,
                  pushed_maps: list | None = None,
                  promoted: list[dict] | None = None,
@@ -218,10 +221,12 @@ class DeviceScanner:
                 jit_vis.append(len(store.jit_blocks))
         version = getattr(store, "data_version", None)
         if version is None or version != self._synced_version:
-            self.cache.sync(store)
+            with span("ciao.plane.sync"):
+                self.cache.sync(store)
             if version is not None:
                 self._synced_version = version
-        batch = compile_scan_batch(queries)
+        with span("ciao.scan.params"):
+            batch = compile_scan_batch(queries)
         slots = list(self.cache.slots)
         Q, S = len(queries), len(slots)
         pushed_bits = np.zeros((Q, S), np.uint32)
@@ -247,8 +252,9 @@ class DeviceScanner:
                 active[qi, si] = 1
         params = None
         if S and active.any():
-            params = self.cache.build_params(
-                batch, pushed_bits=pushed_bits, active=active)
+            with span("ciao.scan.params"):
+                params = self.cache.build_params(
+                    batch, pushed_bits=pushed_bits, active=active)
             self.cache.touch(
                 [si for si in range(S) if active[:, si].any()])
         return _Prepared(
@@ -264,13 +270,15 @@ class DeviceScanner:
         plane = self.cache.plane
         assert plane is not None
         if self.backend == "numpy":
-            if self._np_plane_src is not plane.pres:
-                self._np_plane = tuple(np.asarray(a) for a in plane)
-                self._np_plane_src = plane.pres
-            return scan_core_numpy(*self._np_plane, prep.params)
+            with span("ciao.scan.launch"):
+                if self._np_plane_src is not plane.pres:
+                    self._np_plane = tuple(np.asarray(a) for a in plane)
+                    self._np_plane_src = plane.pres
+                return scan_core_numpy(*self._np_plane, prep.params)
         return scan_counts(plane, prep.params, backend=self.backend,
                            r_blk=self.r_blk)
 
+    @spanned("ciao.scan.assemble")
     def _assemble(self, prep: _Prepared, counts, cands) -> list[ScanResult]:
         store = self.store
         slot_of = {id(s.seg): i for i, s in enumerate(prep.slots)}
@@ -390,37 +398,39 @@ def _spmd_counts(caches: list[DeviceSegmentCache],
     tables are padded to common buckets and laid out back to back, so
     block i of every argument is shard i's.
     """
-    K = max(c.plane.pres.shape[0] for c in caches)
-    N = max(c.plane.pres.shape[1] for c in caches)
-    for c in caches:
-        c.ensure_capacity(K, N)
-    T = max(p.kinds.shape[0] for p in params)
-    C = max(p.membership.shape[0] for p in params)
-    Q = max(p.query_clause.shape[0] for p in params)
-    S1 = max(p.pushed_tbl.shape[1] for p in params)
-    L = max(p.lut_flat.shape[0] for p in params)
-    params = [_pad_params(p, T, C, Q, S1, L) for p in params]
-    if backend == "xla":
-        tables = params
-    else:
-        ts = max(bucket_pow2(int((p.kinds == KIND_SUBSTRING).sum()))
-                 for p in params)
-        tables = [pallas_scan_args(p, K, n_sub=ts) for p in params]
-    mesh = Mesh(np.asarray([c.device for c in caches]), ("shards",))
-    sharding = NamedSharding(mesh, P("shards"))
-    n = len(caches)
+    with span("ciao.scan.launch"):
+        K = max(c.plane.pres.shape[0] for c in caches)
+        N = max(c.plane.pres.shape[1] for c in caches)
+        for c in caches:
+            c.ensure_capacity(K, N)
+        T = max(p.kinds.shape[0] for p in params)
+        C = max(p.membership.shape[0] for p in params)
+        Q = max(p.query_clause.shape[0] for p in params)
+        S1 = max(p.pushed_tbl.shape[1] for p in params)
+        L = max(p.lut_flat.shape[0] for p in params)
+        params = [_pad_params(p, T, C, Q, S1, L) for p in params]
+        if backend == "xla":
+            tables = params
+        else:
+            ts = max(bucket_pow2(int((p.kinds == KIND_SUBSTRING).sum()))
+                     for p in params)
+            tables = [pallas_scan_args(p, K, n_sub=ts) for p in params]
+        mesh = Mesh(np.asarray([c.device for c in caches]), ("shards",))
+        sharding = NamedSharding(mesh, P("shards"))
+        n = len(caches)
 
-    def global_array(bufs):
-        b = bufs[0]
-        return jax.make_array_from_single_device_arrays(
-            (n * b.shape[0],) + b.shape[1:], sharding, list(bufs))
+        def global_array(bufs):
+            b = bufs[0]
+            return jax.make_array_from_single_device_arrays(
+                (n * b.shape[0],) + b.shape[1:], sharding, list(bufs))
 
-    plane = [global_array(x) for x in zip(*(c.plane for c in caches))]
-    args = [jax.device_put(np.concatenate(x), sharding)
-            for x in zip(*tables)]
-    counts, cands = _spmd_program(mesh, backend, r_blk)(*plane, *args)
-    counts = np.asarray(counts).reshape(n, Q, S1)
-    cands = np.asarray(cands).reshape(n, Q, S1)
+        plane = [global_array(x) for x in zip(*(c.plane for c in caches))]
+        args = [jax.device_put(np.concatenate(x), sharding)
+                for x in zip(*tables)]
+        counts, cands = _spmd_program(mesh, backend, r_blk)(*plane, *args)
+    with span("ciao.scan.fetch"):
+        counts = np.asarray(counts).reshape(n, Q, S1)
+        cands = np.asarray(cands).reshape(n, Q, S1)
     return [(counts[i], cands[i]) for i in range(n)]
 
 
@@ -478,44 +488,45 @@ class ShardedDeviceScanner:
         t0 = time.perf_counter()
         store = self.store
         queries = tuple(queries)
-        if self.log_queries:
-            for q in queries:
-                store.log_query(q)
-        # per-shard surviving query subsets (partition prune, level 1)
-        sub: list[list[int]] = []
-        pruned_shards: list[list[int]] = [[] for _ in queries]
-        for s in range(store.n_shards):
-            shard = store.shards[s]
-            if not (shard.stats.n_records or shard.blocks
-                    or shard.jit_blocks or shard.raw):
-                sub.append([])
-                continue
-            qs: list[int] = []
-            for qi, q in enumerate(queries):
-                if store.n_shards > 1 and \
-                        not store.summaries[s].query_possible(q):
-                    pruned_shards[qi].append(s)
-                else:
-                    qs.append(qi)
-            sub.append(qs)
-        # promotions and pruned-shard row snapshots in GLOBAL query
-        # order: sequential scatter-gather scans run query i across every
-        # shard before query i+1, so a shard pruned for query i accounts
-        # its resident rows BEFORE later queries' promotions enlarge them
-        pushed_maps: list[list] = [[] for _ in range(store.n_shards)]
-        promoted: list[list[dict]] = [[] for _ in range(store.n_shards)]
-        jit_vis: list[list[int]] = [[] for _ in range(store.n_shards)]
-        pruned_rows: dict[tuple[int, int], dict] = {}
-        for qi, q in enumerate(queries):
+        with span("ciao.scan.resolve"):
+            if self.log_queries:
+                for q in queries:
+                    store.log_query(q)
+            # per-shard surviving query subsets (partition prune, level 1)
+            sub: list[list[int]] = []
+            pruned_shards: list[list[int]] = [[] for _ in queries]
             for s in range(store.n_shards):
                 shard = store.shards[s]
-                if qi in sub[s]:
-                    pm = shard.pushed_by_epoch(q)
-                    pushed_maps[s].append(pm)
-                    promoted[s].append(dict(shard.promote_uncovered_raw(pm)))
-                    jit_vis[s].append(len(shard.jit_blocks))
-                elif s in pruned_shards[qi]:
-                    pruned_rows[(qi, s)] = shard.resident_group_rows()
+                if not (shard.stats.n_records or shard.blocks
+                        or shard.jit_blocks or shard.raw):
+                    sub.append([])
+                    continue
+                qs: list[int] = []
+                for qi, q in enumerate(queries):
+                    if store.n_shards > 1 and \
+                            not store.summaries[s].query_possible(q):
+                        pruned_shards[qi].append(s)
+                    else:
+                        qs.append(qi)
+                sub.append(qs)
+            # promotions and pruned-shard row snapshots in GLOBAL query
+            # order: sequential scatter-gather scans run query i across every
+            # shard before query i+1, so a shard pruned for query i accounts
+            # its resident rows BEFORE later queries' promotions enlarge them
+            pushed_maps: list[list] = [[] for _ in range(store.n_shards)]
+            promoted: list[list[dict]] = [[] for _ in range(store.n_shards)]
+            jit_vis: list[list[int]] = [[] for _ in range(store.n_shards)]
+            pruned_rows: dict[tuple[int, int], dict] = {}
+            for qi, q in enumerate(queries):
+                for s in range(store.n_shards):
+                    shard = store.shards[s]
+                    if qi in sub[s]:
+                        pm = shard.pushed_by_epoch(q)
+                        pushed_maps[s].append(pm)
+                        promoted[s].append(dict(shard.promote_uncovered_raw(pm)))
+                        jit_vis[s].append(len(shard.jit_blocks))
+                    elif s in pruned_shards[qi]:
+                        pruned_rows[(qi, s)] = shard.resident_group_rows()
         prepared: dict[int, _Prepared] = {}
         for s, qs in enumerate(sub):
             if qs:

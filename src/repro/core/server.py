@@ -26,6 +26,7 @@ Query path (:class:`DataSkippingScanner`, DESIGN.md §13):
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import threading
@@ -42,7 +43,7 @@ from .columnar import (
     query_mask, segment_from_packed,
 )
 from .predicates import Clause, Query, clause_from_obj, clause_to_obj
-from .telemetry import TelemetryPlane
+from .telemetry import TelemetryPlane, span, spanned
 
 
 class StaleEpochError(ValueError):
@@ -425,6 +426,17 @@ class CiaoStore:
         # take it — readers go through immutable snapshots.
         self._ingest_lock = threading.RLock()
 
+    @contextlib.contextmanager
+    def _hold(self):
+        """Hold ``_ingest_lock``; the wait for it is the span
+        ``ciao.store.lock_wait``."""
+        with span("ciao.store.lock_wait"):
+            self._ingest_lock.acquire()
+        try:
+            yield
+        finally:
+            self._ingest_lock.release()
+
     # -- segment surface -----------------------------------------------------
     def _builder(self, epoch: int, n_covered: int, tier: int
                  ) -> SegmentBuilder:
@@ -488,7 +500,7 @@ class CiaoStore:
 
         Taken under the ingest lock so a concurrent ingest can't tear the
         counters mid-report (DESIGN.md §17)."""
-        with self._ingest_lock:
+        with self._hold():
             return self._stats_report_locked()
 
     def _stats_report_locked(self) -> dict:
@@ -566,7 +578,7 @@ class CiaoStore:
             new_plan = family.plan
         else:
             family = trivial_family(new_plan)
-        with self._ingest_lock:
+        with self._hold():
             if new_plan.epoch <= self.plan.epoch:
                 raise ValueError(
                     f"epoch must advance: "
@@ -662,7 +674,7 @@ class CiaoStore:
         per-shard writer queues guarantee this); the lock exists so
         ``snapshot()`` taken from reader threads sees a consistent surface.
         """
-        with self._ingest_lock:
+        with self._hold(), span("ciao.store.ingest"):
             return self._ingest_chunk_locked(
                 chunk, bitvecs, epoch=epoch, tier=tier, objs=objs)
 
@@ -718,10 +730,12 @@ class CiaoStore:
             # as buffer slices, parsed objects straight into the columnar
             # builder (no per-row chunk.record() round-trips)
             tp0 = time.perf_counter()
-            recs, sel_objs = decode_rows(chunk.data, chunk.lengths, load_idx,
-                                         objs=objs)
-            self.segments.extend(
-                self._builder(e, n_cov, tier_idx).add(recs, sel_objs, bits))
+            with span("ciao.store.parse"):
+                recs, sel_objs = decode_rows(
+                    chunk.data, chunk.lengths, load_idx, objs=objs)
+            with span("ciao.store.build"):
+                self.segments.extend(self._builder(e, n_cov, tier_idx)
+                                     .add(recs, sel_objs, bits))
             self.stats.parse_time_s += time.perf_counter() - tp0
         if len(keep_idx):
             self.raw.append(
@@ -751,10 +765,11 @@ class CiaoStore:
         pushes none of a query's clauses); ``None``/``None`` promotes
         everything.  Returns rows promoted per ``(epoch, tier)``.
         """
-        with self._ingest_lock:
+        with self._hold():
             return self._jit_load_raw_locked(
                 only_epochs, only_groups=only_groups)
 
+    @spanned("ciao.store.promote")
     def _jit_load_raw_locked(
         self, only_epochs: set[int] | None = None,
         *, only_groups: set[tuple[int, int]] | None = None,
@@ -809,7 +824,7 @@ class CiaoStore:
         store that never changes while live ingest continues on the
         parent (DESIGN.md §17).
         """
-        with self._ingest_lock:
+        with self._hold(), span("ciao.store.snapshot"):
             return StoreSnapshot(self)
 
     # -- persistence (ingest checkpointing) ----------------------------------
@@ -1185,27 +1200,28 @@ class StoreSnapshot:
                     take.append(rr)
             if not take:
                 return {}
-            t0 = time.perf_counter()
-            promoted: dict[tuple[int, int], int] = {}
-            grouped: dict[tuple[int, int, int], tuple[list, list]] = {}
-            for rr in take:
-                recs, objs = decode_rows(rr.data, rr.lengths)
-                g = grouped.setdefault((rr.epoch, rr.n_covered, rr.tier),
-                                       ([], []))
-                g[0].extend(recs)
-                g[1].extend(objs)
-                self.stats.n_jit_loaded += rr.n
-                key = (rr.epoch, rr.tier)
-                promoted[key] = promoted.get(key, 0) + rr.n
-            for (epoch, n_cov, tier), (recs, objs) in grouped.items():
-                self._jit.extend(build_segments(
-                    recs, np.zeros((0, len(recs)), bool), objs=objs,
-                    epoch=epoch, n_covered=n_cov, tier=tier,
-                    capacity=self.segment_capacity))
-            self._raw = keep
-            self._promotions += 1
-            self.stats.jit_time_s += time.perf_counter() - t0
-            return promoted
+            with span("ciao.store.promote"):
+                t0 = time.perf_counter()
+                promoted: dict[tuple[int, int], int] = {}
+                grouped: dict[tuple[int, int, int], tuple[list, list]] = {}
+                for rr in take:
+                    recs, objs = decode_rows(rr.data, rr.lengths)
+                    g = grouped.setdefault((rr.epoch, rr.n_covered, rr.tier),
+                                           ([], []))
+                    g[0].extend(recs)
+                    g[1].extend(objs)
+                    self.stats.n_jit_loaded += rr.n
+                    key = (rr.epoch, rr.tier)
+                    promoted[key] = promoted.get(key, 0) + rr.n
+                for (epoch, n_cov, tier), (recs, objs) in grouped.items():
+                    self._jit.extend(build_segments(
+                        recs, np.zeros((0, len(recs)), bool), objs=objs,
+                        epoch=epoch, n_covered=n_cov, tier=tier,
+                        capacity=self.segment_capacity))
+                self._raw = keep
+                self._promotions += 1
+                self.stats.jit_time_s += time.perf_counter() - t0
+                return promoted
 
     def close(self) -> None:
         """Retire this snapshot: drop every captured segment reference.

@@ -737,7 +737,7 @@ class ShardedCiaoStore:
         may place rows into the same shard concurrently — the lock makes
         the two mutators mutually exclusive per shard."""
         sh = self.shards[s]
-        with sh._ingest_lock:
+        with sh._hold():
             self.summaries[s].update(objs)
             sh.ingest_chunk(chunk, bitvecs,
                             epoch=epoch, tier=tier, objs=objs)

@@ -18,6 +18,7 @@ from repro.core import bitvector
 from repro.core.bitvector import ChunkBitvectors
 from repro.core.client import Chunk
 from repro.core.predicates import Clause
+from repro.core.telemetry import span
 
 from . import ops
 from .plan import CompiledPlan, compile_plan, tier_view  # noqa: F401 (re-export)
@@ -65,10 +66,11 @@ class KernelEngine:
                 counts=np.zeros((C,), np.int32),
                 n_records=R,
             )
-        plan = self._compiled(tuple(clauses))
-        words, or_words, counts = ops.clause_bitvectors(
-            chunk.data, plan, backend=self.backend, r_blk=self.r_blk,
-        )
+        with span("ciao.client.pushdown"):
+            plan = self._compiled(tuple(clauses))
+            words, or_words, counts = ops.clause_bitvectors(
+                chunk.data, plan, backend=self.backend, r_blk=self.r_blk,
+            )
         return ChunkBitvectors(
             words=words, or_words=or_words, counts=counts, n_records=R
         )
@@ -100,10 +102,11 @@ class KernelEngine:
                 counts=np.zeros((n_clauses,), np.int32),
                 n_records=R,
             )
-        view = self._compiled_tier(clauses, n_clauses)
-        words, or_words, counts = ops.clause_bitvectors(
-            chunk.data, view, backend=self.backend, r_blk=self.r_blk,
-        )
+        with span("ciao.client.pushdown"):
+            view = self._compiled_tier(clauses, n_clauses)
+            words, or_words, counts = ops.clause_bitvectors(
+                chunk.data, view, backend=self.backend, r_blk=self.r_blk,
+            )
         # out-of-tier clause rows are all-zero by construction: slice them
         # off so the store sees exactly the tier's coverage
         return ChunkBitvectors(

@@ -248,6 +248,7 @@ def clause_bitvectors_fused(
             pltpu.VMEM((C, r_blk), jnp.int32),
         ],
         interpret=interpret,
+        name="clause_bitvectors_fused",   # the trace's name for the kernel
     )(keys, klens, vals, vlens, kinds, unbounded, membership, n_valid,
       data.T)
     words = jnp.transpose(words, (1, 0, 2)).reshape(C, R // WORD_BITS)

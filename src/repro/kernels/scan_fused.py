@@ -70,6 +70,7 @@ from jax.experimental import pallas as pl
 from repro.core.predicates import (
     Clause, Kind, Query, SimplePredicate, lowerable,
 )
+from repro.core.telemetry import span
 
 from .plan import compile_query_batch
 from .residual import _pow2
@@ -473,6 +474,7 @@ def _scan_core_pallas(pres, notn, isb, numv, scod, rcod, sid, cw,
             jax.ShapeDtypeStruct((Q, S1), jnp.float32),
         ],
         interpret=interpret,
+        name="_scan_core_pallas",   # the trace's name for the kernel
     )(keym, pres, notn, isb, numv, scod, rcod,
       sid.reshape(1, N), jax.lax.bitcast_convert_type(cw, jnp.int32)
       .reshape(1, N), sub, subsel, kinds, code_a, num_codes,
@@ -592,46 +594,48 @@ def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
     (the host reference — converts the plane per call; perf baselines
     should pre-convert and call :func:`scan_core_numpy` directly).
     """
-    if backend == "numpy":
-        counts, cands = scan_core_numpy(
-            *(np.asarray(a) for a in plane), params)
-        return counts, cands
-    if backend == "xla":
-        # sort term rows by kind (stable; bucket padding, kind -1, goes
-        # last) so the launch can evaluate each row's own branch only.
-        # Membership columns permute with them — results are identical.
-        kinds = params.kinds
-        order = np.argsort(
-            np.where(kinds < 0, np.int32(KIND_KV + 1), kinds),
-            kind="stable")
-        kc = tuple(int((kinds == k).sum())
-                   for k in (KIND_PRESENCE, KIND_EXACT,
-                             KIND_SUBSTRING, KIND_KV))
-        counts, cands = _scan_core_xla(
-            *plane,
-            jnp.asarray(params.key_ids[order]),
-            jnp.asarray(params.kinds[order]),
-            jnp.asarray(params.code_a[order]),
-            jnp.asarray(params.num_codes[order]),
-            jnp.asarray(params.lut_off[order]),
-            jnp.asarray(params.lut_flat),
-            jnp.asarray(params.is_null[order]),
-            jnp.asarray(params.is_boolv[order]),
-            jnp.asarray(params.membership[:, order]),
-            jnp.asarray(params.query_clause),
-            jnp.asarray(params.pushed_tbl), jnp.asarray(params.active),
-            kind_counts=kc,
-        )
-    elif backend in ("pallas", "pallas_interpret"):
-        args = pallas_scan_args(params, plane.pres.shape[0])
-        counts, cands = _scan_core_pallas(
-            *plane, *(jnp.asarray(a) for a in args),
-            r_blk=min(r_blk, plane.sid.shape[0]),
-            interpret=(backend == "pallas_interpret"),
-        )
-    else:
-        raise ValueError(f"unknown device scan backend {backend!r}")
-    return np.asarray(counts), np.asarray(cands)
+    with span("ciao.scan.launch"):
+        if backend == "numpy":
+            counts, cands = scan_core_numpy(
+                *(np.asarray(a) for a in plane), params)
+            return counts, cands
+        if backend == "xla":
+            # sort term rows by kind (stable; bucket padding, kind -1, goes
+            # last) so the launch can evaluate each row's own branch only.
+            # Membership columns permute with them — results are identical.
+            kinds = params.kinds
+            order = np.argsort(
+                np.where(kinds < 0, np.int32(KIND_KV + 1), kinds),
+                kind="stable")
+            kc = tuple(int((kinds == k).sum())
+                       for k in (KIND_PRESENCE, KIND_EXACT,
+                                 KIND_SUBSTRING, KIND_KV))
+            counts, cands = _scan_core_xla(
+                *plane,
+                jnp.asarray(params.key_ids[order]),
+                jnp.asarray(params.kinds[order]),
+                jnp.asarray(params.code_a[order]),
+                jnp.asarray(params.num_codes[order]),
+                jnp.asarray(params.lut_off[order]),
+                jnp.asarray(params.lut_flat),
+                jnp.asarray(params.is_null[order]),
+                jnp.asarray(params.is_boolv[order]),
+                jnp.asarray(params.membership[:, order]),
+                jnp.asarray(params.query_clause),
+                jnp.asarray(params.pushed_tbl), jnp.asarray(params.active),
+                kind_counts=kc,
+            )
+        elif backend in ("pallas", "pallas_interpret"):
+            args = pallas_scan_args(params, plane.pres.shape[0])
+            counts, cands = _scan_core_pallas(
+                *plane, *(jnp.asarray(a) for a in args),
+                r_blk=min(r_blk, plane.sid.shape[0]),
+                interpret=(backend == "pallas_interpret"),
+            )
+        else:
+            raise ValueError(f"unknown device scan backend {backend!r}")
+    with span("ciao.scan.fetch"):
+        return np.asarray(counts), np.asarray(cands)
 
 
 def bucket_pow2(n: int, floor: int = 1) -> int:

@@ -58,6 +58,7 @@ from repro.core.server import (
 )
 from repro.core.shard import ShardedCiaoStore, ShardedScanner
 from repro.core.predicates import Query
+from repro.core.telemetry import span, spanned
 
 
 class BackpressureError(RuntimeError):
@@ -307,6 +308,7 @@ class CiaoServeEngine:
         self.drained = 0            # slices applied by the writer pool
         self.rejected = 0           # submits refused by backpressure
         self.blocked_s = 0.0        # submit time spent waiting on queues
+        self.queued_s = 0.0         # slice time from enqueue to dequeue
         self._errors: list[BaseException] = []
         self._closed = False
         # zero reader blocking: readers take self._readers by atomic
@@ -353,6 +355,7 @@ class CiaoServeEngine:
         return self.store.stats
 
     # -- ingest (submit side) --------------------------------------------------
+    @spanned("ciao.serve.submit")
     def ingest_chunk(self, chunk, bitvecs, *, epoch: int | None = None,
                      tier: int | None = None,
                      tenant: str = "default") -> LoadStats:
@@ -391,6 +394,8 @@ class CiaoServeEngine:
     def _enqueue(self, item, tenant: str = "default") -> None:
         q = self._queues[item[0] % self.writers]
         tele = getattr(self.store, "telemetry", None)
+        # the enqueue time travels with the slice, for queued_s
+        item = (*item, time.perf_counter())
         if self.backpressure == "reject":
             try:
                 q.put_nowait(item)
@@ -420,7 +425,11 @@ class CiaoServeEngine:
             try:
                 if item is None:
                     return
-                self._apply(item)
+                waited = time.perf_counter() - item[-1]
+                with self._stats_lock:
+                    self.queued_s += waited
+                with span("ciao.serve.apply"):
+                    self._apply(item[:-1])
                 with self._stats_lock:
                     self.drained += 1
             except BaseException as e:     # pragma: no cover - defensive
@@ -479,8 +488,9 @@ class CiaoServeEngine:
             readers = self._readers
             if readers is None or \
                     readers.snap.base_version != self.store.data_version:
-                self._readers = _SnapshotReaders(
-                    self, self.store.snapshot())
+                with span("ciao.serve.refresh"):
+                    self._readers = _SnapshotReaders(
+                        self, self.store.snapshot())
 
     def _refresh_loop(self) -> None:
         while not self._stop.wait(self.refresh_interval_s):
@@ -532,6 +542,7 @@ class CiaoServeEngine:
             return out
         return self._admitted(tenant, run, record=False)
 
+    @spanned("ciao.serve.query")
     def _admitted(self, tenant: str, fn, *, record: bool = True):
         tier = self.admission.acquire(tenant) if self.admission else None
         try:
@@ -600,6 +611,7 @@ class CiaoServeEngine:
                 "drained": self.drained,
                 "rejected": self.rejected,
                 "blocked_s": round(self.blocked_s, 6),
+                "queued_s": round(self.queued_s, 6),
                 "queue_depths": [q.qsize() for q in self._queues],
                 "errors": len(self._errors),
             }

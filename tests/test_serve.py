@@ -409,6 +409,30 @@ def test_engine_backpressure_block(ycsb):
         serve.close()
 
 
+def test_engine_queued_s_counts_slices_waiting_for_the_writer(ycsb):
+    """A writer slower than its submitter: the slices behind the one it
+    is applying wait in the queue, and ``queued_s`` (enqueue to dequeue,
+    summed) counts that wait; the submitter never blocked."""
+    recs, objs, pool = ycsb
+    fam = _family(pool)
+    chunks = _encode_chunks(recs, fam)[:3]
+    store = CiaoStore(fam, segment_capacity=256)
+    serve = CiaoServeEngine(store, queue_depth=8, backpressure="block")
+    try:
+        with store._ingest_lock:        # the writer stalls on its first
+            for ch, bv, tier in chunks:
+                serve.ingest_chunk(ch, bv, epoch=fam.plan.epoch, tier=tier)
+            time.sleep(0.2)
+        serve.quiesce()
+        eng = serve.stats_report()["engine"]
+        # slices 2 and 3 each waited at least the 0.2 s the lock was held
+        assert serve.queued_s >= 0.35
+        assert eng["queued_s"] == round(serve.queued_s, 6)
+        assert eng["blocked_s"] < 0.05 and eng["drained"] == 3
+    finally:
+        serve.close()
+
+
 def test_admission_control(ycsb):
     recs, objs, pool = ycsb
     # unit: reject tier refuses at quota, block tier queues
