@@ -225,7 +225,14 @@ def run(records: list[bytes], *, n_shards: int = 1,
     for i, s in enumerate(shards):
         _log(f"plane[{i}]: device {s['device']}, {s['slots']} slots, "
              f"{s['plane_bytes']} bytes, {s['uploads']} uploads "
-             f"({s['upload_bytes']} bytes), {s['evictions']} evictions")
+             f"({s['upload_bytes']} bytes), {s['evictions']} evictions; "
+             f"{s['launches']} launches, {s['param_transfers']} parameter "
+             f"transfers ({s['param_bytes']} bytes), {s['fetches']} fetches")
+    if n_shards == 1:
+        _check(plane["launches"] > 0
+               and plane["param_transfers"] == plane["launches"]
+               and plane["fetches"] == plane["launches"],
+               "a launch made more than one parameter transfer or fetch")
     if n_shards > 1:
         homes = {s["device"] for s in shards}
         _check(len(homes) == n_shards,

@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import functools
 import time
-from dataclasses import dataclass
+from dataclasses import asdict, dataclass
 from typing import Sequence
 
 import jax
@@ -59,9 +59,9 @@ from repro.core.server import CiaoStore, DataSkippingScanner, ScanResult
 from repro.core.shard import ShardedCiaoStore, merge_scan_results
 from repro.core.telemetry import span, spanned
 from repro.kernels.scan_fused import (
-    KIND_SUBSTRING, ScanBatch, ScanParams, _scan_core_pallas, bucket_pow2,
-    compile_scan_batch, pallas_scan_args, scan_core_numpy, scan_core_xla,
-    scan_counts,
+    KIND_SUBSTRING, LaunchCounters, ScanBatch, ScanParams, _scan_core_pallas,
+    bucket_pow2, compile_scan_batch, pallas_scan_args, scan_core_numpy,
+    scan_core_xla, scan_counts,
 )
 
 
@@ -109,6 +109,8 @@ class DeviceScanner:
             else None
         self.tenant = tenant
         self._synced_version = -1
+        # host <-> device traffic of this scanner's own launches
+        self.counters = LaunchCounters()
         # backend="numpy" baseline: host mirror of the plane, converted
         # once per plane generation (not per scan)
         self._np_plane = None
@@ -121,7 +123,8 @@ class DeviceScanner:
     # -- public API ---------------------------------------------------------
 
     def plane_stats(self) -> dict:
-        """Residency counters of this scanner's segment plane."""
+        """Residency counters of this scanner's segment plane, and the
+        launches' parameter uploads and result fetches."""
         c, p = self.cache, self.cache.plane
         return {
             "device": None if p is None else str(*p.pres.devices()),
@@ -130,6 +133,7 @@ class DeviceScanner:
             "uploads": c.uploads,
             "upload_bytes": c.upload_bytes,
             "evictions": c.evictions,
+            **asdict(self.counters),
         }
 
     def scan(self, q: Query) -> ScanResult:
@@ -270,13 +274,14 @@ class DeviceScanner:
         plane = self.cache.plane
         assert plane is not None
         if self.backend == "numpy":
+            self.counters.launches += 1
             with span("ciao.scan.launch"):
                 if self._np_plane_src is not plane.pres:
                     self._np_plane = tuple(np.asarray(a) for a in plane)
                     self._np_plane_src = plane.pres
                 return scan_core_numpy(*self._np_plane, prep.params)
         return scan_counts(plane, prep.params, backend=self.backend,
-                           r_blk=self.r_blk)
+                           r_blk=self.r_blk, counters=self.counters)
 
     @spanned("ciao.scan.assemble")
     def _assemble(self, prep: _Prepared, counts, cands) -> list[ScanResult]:
@@ -477,7 +482,9 @@ class ShardedDeviceScanner:
         return [sc.cache for sc in self._scanners]
 
     def plane_stats(self) -> dict:
-        """Per-shard residency counters (see ``DeviceScanner``)."""
+        """Per-shard residency counters (see ``DeviceScanner``); a
+        shard's launch counters count its own launches, not the SPMD
+        launch's."""
         return {"spmd": self.spmd,
                 "shards": [sc.plane_stats() for sc in self._scanners]}
 

@@ -51,7 +51,9 @@ uint8 and widen through int32, since Mosaic has no uint8 -> f32 cast.
 The substring LUT probe is a data-dependent gather into a LUT of
 unbounded length, which Mosaic cannot lower: it runs as 1-D XLA gathers
 in the same jitted program, one row per substring term, ahead of the
-kernel).  Both return
+kernel; :func:`scan_counts` sends its parameter tables packed in one
+int32 buffer, one upload, and fetches counts and cands in one copy).
+Both return
 ``counts[Q, S]`` / ``cands[Q, S]`` (matches, pushed-candidate rows) per
 cache slot, which the device scanner folds into the standard per-(epoch,
 tier) :class:`~repro.core.server.ScanResult` accounting.
@@ -59,6 +61,7 @@ tier) :class:`~repro.core.server.ScanResult` accounting.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import NamedTuple, Sequence
 
@@ -482,34 +485,127 @@ def _scan_core_pallas(pres, notn, isb, numv, scod, rcod, sid, cw,
     return counts.astype(jnp.int32), cands.astype(jnp.int32)
 
 
-def pallas_scan_args(params: ScanParams, n_keys: int,
-                     n_sub: int | None = None) -> tuple:
-    """Host-side parameter tables of :func:`_scan_core_pallas`, in its
-    argument order after the eight plane arrays.  ``n_sub`` pads the
-    substring-probe rows to a common bucket (SPMD launches)."""
+#: dtypes of the packed parameter buffer's tables.  Every element is one
+#: int32 word: f32 tables travel as their bit pattern, the uint8 substring
+#: LUT widened (it is only gathered and compared ``> 0``)
+_F32, _I32, _U8 = "float32", "int32", "uint8"
+
+
+@functools.lru_cache(maxsize=256)
+def scan_args_layout(T: int, K: int, C: int, Q: int, S1: int, Ts: int,
+                     L: int) -> tuple:
+    """Static layout of the packed parameter buffer: one ``(offset,
+    shape, dtype)`` per table of :func:`_scan_core_pallas`, in its
+    argument order after the eight plane arrays.  It depends on the
+    bucketed shapes alone, so the same buckets reuse one trace."""
+    tables = (
+        ((T, K), _F32),                       # keym
+        ((T, 1), _I32),                       # kinds
+        ((T, S1), _F32),                      # code_a
+        ((3, T, S1), _F32),                   # num_codes
+        ((Ts,), _I32), ((Ts, S1), _I32),      # sub_keys / sub_off
+        ((T, Ts), _F32),                      # subsel
+        ((L,), _U8),                          # lut_flat
+        ((T, 1), _I32), ((T, 1), _I32),       # is_null / is_boolv
+        ((C, T), _F32), ((Q, C), _F32),       # membership / query_clause
+        ((Q, S1), _F32), ((Q, S1), _F32),     # plo / phi
+        ((Q, S1), _F32),                      # active
+    )
+    out, off = [], 0
+    for shape, dtype in tables:
+        out.append((off, shape, dtype))
+        off += math.prod(shape)
+    return tuple(out)
+
+
+def _layout_words(layout: tuple) -> int:
+    off, shape, _ = layout[-1]
+    return off + math.prod(shape)
+
+
+def _host_tables(buf: np.ndarray, layout: tuple) -> list[np.ndarray]:
+    """Writable views of each table in the packed host buffer (f32
+    tables as float32 views of their words, the LUT as its int32 words)."""
+    out = []
+    for off, shape, dtype in layout:
+        words = buf[off:off + math.prod(shape)].reshape(shape)
+        out.append(words.view(np.float32) if dtype == _F32 else words)
+    return out
+
+
+def pack_scan_args(params: ScanParams, n_keys: int,
+                   n_sub: int | None = None) -> tuple[np.ndarray, tuple]:
+    """The parameter tables of :func:`_scan_core_pallas`, built straight
+    into one contiguous ``int32`` host buffer; returns it with its
+    :func:`scan_args_layout`.  ``n_sub`` pads the substring-probe rows to
+    a common bucket (SPMD launches)."""
     T = params.kinds.shape[0]
-    f32 = np.float32
-    keym = np.zeros((T, n_keys), f32)
-    keym[np.arange(T), params.key_ids] = 1.0
+    C = params.membership.shape[0]
+    Q, S1 = params.pushed_tbl.shape
     sub_rows = np.flatnonzero(params.kinds == KIND_SUBSTRING)
     Ts = n_sub or bucket_pow2(len(sub_rows))
-    subsel = np.zeros((T, Ts), f32)
-    subsel[sub_rows, np.arange(len(sub_rows))] = 1.0
-    sub_keys = np.zeros((Ts,), np.int32)
+    layout = scan_args_layout(T, n_keys, C, Q, S1, Ts,
+                              params.lut_flat.shape[0])
+    buf = np.zeros(_layout_words(layout), np.int32)   # 0 is also 0.0f
+    (keym, kinds, code_a, num_codes, sub_keys, sub_off, subsel, lut,
+     is_null, is_boolv, membership, query_clause, plo, phi,
+     active) = _host_tables(buf, layout)
+    keym[np.arange(T), params.key_ids] = 1.0
+    kinds[:, 0] = params.kinds
+    code_a[...] = params.code_a
+    num_codes[...] = params.num_codes.transpose(1, 0, 2)
     sub_keys[:len(sub_rows)] = params.key_ids[sub_rows]
-    sub_off = np.full((Ts, params.lut_off.shape[1]), -1, np.int32)
+    sub_off[...] = -1
     sub_off[:len(sub_rows)] = params.lut_off[sub_rows]
-    col = lambda a: a.astype(np.int32).reshape(-1, 1)  # noqa: E731
-    return (
-        keym, col(params.kinds), params.code_a.astype(f32),
-        params.num_codes.transpose(1, 0, 2).astype(f32),
-        sub_keys, sub_off, subsel, params.lut_flat,
-        col(params.is_null), col(params.is_boolv),
-        params.membership.astype(f32), params.query_clause.astype(f32),
-        (params.pushed_tbl & np.uint32(0xFFFF)).astype(f32),
-        (params.pushed_tbl >> np.uint32(16)).astype(f32),
-        params.active.astype(f32),
-    )
+    subsel[sub_rows, np.arange(len(sub_rows))] = 1.0
+    lut[...] = params.lut_flat
+    is_null[:, 0] = params.is_null
+    is_boolv[:, 0] = params.is_boolv
+    membership[...] = params.membership
+    query_clause[...] = params.query_clause
+    plo[...] = params.pushed_tbl & np.uint32(0xFFFF)
+    phi[...] = params.pushed_tbl >> np.uint32(16)
+    active[...] = params.active
+    return buf, layout
+
+
+def pallas_scan_args(params: ScanParams, n_keys: int,
+                     n_sub: int | None = None) -> tuple:
+    """The tables of :func:`pack_scan_args` one by one, each in the
+    dtype :func:`_scan_core_pallas` takes (the SPMD launch uploads them
+    table by table)."""
+    buf, layout = pack_scan_args(params, n_keys, n_sub)
+    return tuple(t.astype(dtype, copy=False) for t, (_, _, dtype)
+                 in zip(_host_tables(buf, layout), layout))
+
+
+def unpack_scan_args(packed: jnp.ndarray, layout: tuple) -> list:
+    """Device side of :func:`pack_scan_args`: static slices of the packed
+    words, each cast back to its table's dtype."""
+    out = []
+    for off, shape, dtype in layout:
+        a = jax.lax.slice_in_dim(packed, off, off + math.prod(shape))
+        a = a.reshape(shape)
+        if dtype == _F32:
+            a = jax.lax.bitcast_convert_type(a, jnp.float32)
+        elif dtype == _U8:
+            a = a.astype(jnp.uint8)
+        out.append(a)
+    return out
+
+
+@functools.partial(
+    jax.jit, static_argnames=("layout", "r_blk", "interpret"))
+def _scan_core_pallas_packed(pres, notn, isb, numv, scod, rcod, sid, cw,
+                             packed, *, layout: tuple, r_blk: int,
+                             interpret: bool):
+    """:func:`_scan_core_pallas` over one packed ``int32`` parameter
+    buffer (:func:`pack_scan_args`), unpacked in the same program.
+    Returns ``int32[2, Q, S1]``: counts and cands stacked, one fetch."""
+    counts, cands = _scan_core_pallas(
+        pres, notn, isb, numv, scod, rcod, sid, cw,
+        *unpack_scan_args(packed, layout), r_blk=r_blk, interpret=interpret)
+    return jnp.stack([counts, cands])
 
 
 # ---------------------------------------------------------------------------
@@ -583,8 +679,19 @@ def scan_core_numpy(pres, notn, isb, numv, scod, rcod, sid, cw,
     return counts, cands
 
 
+@dataclass
+class LaunchCounters:
+    """Host <-> device traffic of the scan launches they are handed to."""
+
+    launches: int = 0
+    param_transfers: int = 0   # host -> device parameter uploads
+    param_bytes: int = 0
+    fetches: int = 0           # device -> host result copies
+
+
 def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
                 backend: str = "pallas", r_blk: int = 512,
+                counters: LaunchCounters | None = None,
                 ) -> tuple[np.ndarray, np.ndarray]:
     """One fused launch over the plane; ``(counts, cands)`` as int32[Q, S1].
 
@@ -593,7 +700,19 @@ def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
     artifact), ``"pallas"`` (compiled, real hardware), or ``"numpy"``
     (the host reference — converts the plane per call; perf baselines
     should pre-convert and call :func:`scan_core_numpy` directly).
+    The pallas backends send every parameter table in one packed upload
+    and fetch counts and cands in one copy.  ``counters`` counts the
+    launch's transfers.
     """
+    if counters is None:
+        counters = LaunchCounters()
+    counters.launches += 1
+
+    def put(a: np.ndarray):
+        counters.param_transfers += 1
+        counters.param_bytes += a.nbytes
+        return jax.device_put(a, plane.pres.sharding)
+
     with span("ciao.scan.launch"):
         if backend == "numpy":
             counts, cands = scan_core_numpy(
@@ -610,32 +729,40 @@ def scan_counts(plane: DevicePlaneArrays, params: ScanParams, *,
             kc = tuple(int((kinds == k).sum())
                        for k in (KIND_PRESENCE, KIND_EXACT,
                                  KIND_SUBSTRING, KIND_KV))
-            counts, cands = _scan_core_xla(
+            out = _scan_core_xla(
                 *plane,
-                jnp.asarray(params.key_ids[order]),
-                jnp.asarray(params.kinds[order]),
-                jnp.asarray(params.code_a[order]),
-                jnp.asarray(params.num_codes[order]),
-                jnp.asarray(params.lut_off[order]),
-                jnp.asarray(params.lut_flat),
-                jnp.asarray(params.is_null[order]),
-                jnp.asarray(params.is_boolv[order]),
-                jnp.asarray(params.membership[:, order]),
-                jnp.asarray(params.query_clause),
-                jnp.asarray(params.pushed_tbl), jnp.asarray(params.active),
+                put(params.key_ids[order]),
+                put(params.kinds[order]),
+                put(params.code_a[order]),
+                put(params.num_codes[order]),
+                put(params.lut_off[order]),
+                put(params.lut_flat),
+                put(params.is_null[order]),
+                put(params.is_boolv[order]),
+                put(params.membership[:, order]),
+                put(params.query_clause),
+                put(params.pushed_tbl), put(params.active),
                 kind_counts=kc,
             )
         elif backend in ("pallas", "pallas_interpret"):
-            args = pallas_scan_args(params, plane.pres.shape[0])
-            counts, cands = _scan_core_pallas(
-                *plane, *(jnp.asarray(a) for a in args),
-                r_blk=min(r_blk, plane.sid.shape[0]),
-                interpret=(backend == "pallas_interpret"),
-            )
+            with span("ciao.scan.pack"):
+                buf, layout = pack_scan_args(params, plane.pres.shape[0])
+            with span("ciao.scan.upload"):
+                packed = put(buf)
+            with span("ciao.scan.dispatch"):
+                out = _scan_core_pallas_packed(
+                    *plane, packed, layout=layout,
+                    r_blk=min(r_blk, plane.sid.shape[0]),
+                    interpret=(backend == "pallas_interpret"))
         else:
             raise ValueError(f"unknown device scan backend {backend!r}")
     with span("ciao.scan.fetch"):
-        return np.asarray(counts), np.asarray(cands)
+        if backend == "xla":
+            counters.fetches += 2
+            return np.asarray(out[0]), np.asarray(out[1])
+        counters.fetches += 1
+        both = np.asarray(out)
+        return both[0], both[1]
 
 
 def bucket_pow2(n: int, floor: int = 1) -> int:

@@ -364,6 +364,164 @@ def test_residual_pow2_buckets_pin_trace_count():
 
 
 # ---------------------------------------------------------------------------
+# packed launch: one parameter upload, one result fetch
+# ---------------------------------------------------------------------------
+
+P_KEYS, P_ROWS, P_TERMS, P_CLAUSES, P_LUT = 8, 256, 8, 4, 64
+
+
+def _synthetic_launch(n_queries: int, n_slots: int, substring: bool):
+    """A random plane and parameter tables of valid ranges: every kind
+    (substring terms only where asked), pushed words with bits in both
+    16-bit halves, padding rows and slots."""
+    from repro.kernels.scan_fused import (
+        KIND_EXACT, KIND_KV, KIND_PRESENCE, KIND_SUBSTRING,
+        DevicePlaneArrays, ScanParams,
+    )
+    rng = np.random.default_rng([n_queries, n_slots, int(substring)])
+    K, N, T, C, L = P_KEYS, P_ROWS, P_TERMS, P_CLAUSES, P_LUT
+    Q, S1 = n_queries, n_slots
+    pick = [KIND_PRESENCE, KIND_EXACT, KIND_KV]
+    kinds = rng.choice(pick + [KIND_SUBSTRING] * 2 * substring, T)
+    if substring:
+        kinds[:2] = KIND_SUBSTRING
+    kinds[-1] = -1                                   # a padding row
+    import jax.numpy as jnp
+    u8 = lambda shape: rng.integers(0, 2, shape).astype(np.uint8)  # noqa: E731
+    plane = DevicePlaneArrays(
+        *(jnp.asarray(u8((K, N))) for _ in range(4)),
+        jnp.asarray(rng.integers(-1, 6, (K, N)).astype(np.int32)),
+        jnp.asarray(rng.integers(-1, 6, (K, N)).astype(np.int32)),
+        jnp.asarray(rng.integers(-1, S1 - 1, N).astype(np.int32)),
+        jnp.asarray(rng.integers(0, 2**32, N, dtype=np.uint32)))
+    pushed = np.zeros((Q, S1), np.uint32)
+    for bit in (0, 5, 17, 31):
+        pushed |= (rng.random((Q, S1)) < 0.3).astype(np.uint32) << \
+            np.uint32(bit)
+    params = ScanParams(
+        key_ids=rng.integers(0, K, T).astype(np.int32),
+        kinds=kinds.astype(np.int32),
+        code_a=rng.integers(-2, 6, (T, S1)).astype(np.int32),
+        num_codes=rng.integers(-2, 6, (T, 3, S1)).astype(np.int32),
+        lut_off=rng.integers(-1, L - 8, (T, S1)).astype(np.int32),
+        lut_flat=u8(L), is_null=u8(T), is_boolv=u8(T),
+        membership=u8((C, T)), query_clause=u8((Q, C)),
+        pushed_tbl=pushed, active=u8((Q, S1)))
+    return plane, params
+
+
+def _tables_one_by_one(params, n_keys: int) -> tuple:
+    """The parameter tables of ``_scan_core_pallas`` built one at a time,
+    each in its own dtype: what the packed buffer has to carry."""
+    from repro.kernels.scan_fused import KIND_SUBSTRING, bucket_pow2
+    T = params.kinds.shape[0]
+    f32 = np.float32
+    keym = np.zeros((T, n_keys), f32)
+    keym[np.arange(T), params.key_ids] = 1.0
+    sub_rows = np.flatnonzero(params.kinds == KIND_SUBSTRING)
+    Ts = bucket_pow2(len(sub_rows))
+    subsel = np.zeros((T, Ts), f32)
+    subsel[sub_rows, np.arange(len(sub_rows))] = 1.0
+    sub_keys = np.zeros((Ts,), np.int32)
+    sub_keys[:len(sub_rows)] = params.key_ids[sub_rows]
+    sub_off = np.full((Ts, params.lut_off.shape[1]), -1, np.int32)
+    sub_off[:len(sub_rows)] = params.lut_off[sub_rows]
+    col = lambda a: a.astype(np.int32).reshape(-1, 1)  # noqa: E731
+    return (
+        keym, col(params.kinds), params.code_a.astype(f32),
+        params.num_codes.transpose(1, 0, 2).astype(f32),
+        sub_keys, sub_off, subsel, params.lut_flat,
+        col(params.is_null), col(params.is_boolv),
+        params.membership.astype(f32), params.query_clause.astype(f32),
+        (params.pushed_tbl & np.uint32(0xFFFF)).astype(f32),
+        (params.pushed_tbl >> np.uint32(16)).astype(f32),
+        params.active.astype(f32),
+    )
+
+
+@pytest.mark.parametrize("substring", [False, True])
+@pytest.mark.parametrize("n_slots", [2, 64])
+@pytest.mark.parametrize("n_queries", [1, 8])
+def test_packed_launch_bit_identical(n_queries, n_slots, substring):
+    """The packed ``pallas_interpret`` launch against the unpacked kernel
+    call and the numpy reference: the same counts and cands, bit for bit,
+    from one parameter transfer and one fetch."""
+    import jax.numpy as jnp
+    from repro.kernels.scan_fused import (
+        LaunchCounters, _scan_core_pallas, scan_core_numpy, scan_counts,
+    )
+    plane, params = _synthetic_launch(n_queries, n_slots, substring)
+    want = scan_core_numpy(*(np.asarray(a) for a in plane), params)
+    counters = LaunchCounters()
+    packed = scan_counts(plane, params, backend="pallas_interpret",
+                         r_blk=128, counters=counters)
+    unpacked = _scan_core_pallas(
+        *plane, *(jnp.asarray(a) for a in _tables_one_by_one(params, P_KEYS)),
+        r_blk=128, interpret=True)
+    for got, one_by_one, ref in zip(packed, unpacked, want):
+        assert got.dtype == np.int32
+        assert got.shape == (n_queries, n_slots)
+        np.testing.assert_array_equal(got, np.asarray(one_by_one))
+        np.testing.assert_array_equal(got, ref)
+    assert want[1].sum() > 0                  # candidates were counted
+    assert (counters.launches, counters.param_transfers,
+            counters.fetches) == (1, 1, 1)
+    assert counters.param_bytes > 0
+
+
+@pytest.mark.parametrize("substring", [False, True])
+def test_pack_layout_round_trips_every_table(substring):
+    """Every table comes back out of the packed buffer, on the host (the
+    SPMD launch's per-table path) and on the device, with its own bytes
+    and dtype; the layout is static, hashable and leaves no gap."""
+    import jax.numpy as jnp
+    from repro.kernels.scan_fused import (
+        pack_scan_args, pallas_scan_args, unpack_scan_args,
+    )
+    _, params = _synthetic_launch(8, 64, substring)
+    want = _tables_one_by_one(params, P_KEYS)
+    buf, layout = pack_scan_args(params, P_KEYS)
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    assert hash(layout) == hash(pack_scan_args(params, P_KEYS)[1])
+    ends = [off + int(np.prod(shape)) for off, shape, _ in layout]
+    assert [off for off, _, _ in layout] == [0] + ends[:-1]
+    assert ends[-1] == buf.size
+    host = pallas_scan_args(params, P_KEYS)
+    device = unpack_scan_args(jnp.asarray(buf), layout)
+    assert len(want) == len(host) == len(device) == len(layout)
+    for w, h, d in zip(want, host, device):
+        for got in (h, np.asarray(d)):
+            assert got.dtype == w.dtype and got.shape == w.shape
+            assert got.tobytes() == w.tobytes()
+
+
+def test_device_scanner_one_transfer_and_fetch_per_launch(ycsb):
+    """Each launch of a ``DeviceScanner`` uploads its parameters once and
+    fetches its results once, and queries in the same shape buckets
+    reuse one trace of the packed program."""
+    from repro.kernels.scan_fused import _scan_core_pallas_packed
+    recs, objs, ranked = ycsb
+    fam0, fam1 = _families(ranked)
+    store = _build(CiaoStore(fam0, segment_capacity=512), recs, fam0, fam1)
+    host = DataSkippingScanner(store, log_queries=False)
+    dev = DeviceScanner(store, backend="pallas_interpret", log_queries=False)
+    queries = [Query((clause(key_value("linear_score", v)),))
+               for v in (3, 55, 97)]
+    base = _scan_core_pallas_packed._cache_size()
+    traced = []
+    for q in queries:
+        assert _accounting(dev.scan(q)) == _accounting(host.scan(q)), \
+            q.describe()
+        traced.append(_scan_core_pallas_packed._cache_size() - base)
+    assert traced[0] <= 1 and traced == [traced[0]] * len(queries)
+    stats = dev.plane_stats()
+    assert stats["launches"] == len(queries)
+    assert stats["param_transfers"] == stats["launches"]
+    assert stats["fetches"] == stats["launches"]
+    assert stats["param_bytes"] > 0
+
+
+# ---------------------------------------------------------------------------
 # SPMD shard_map path (subprocess: 4 host devices)
 # ---------------------------------------------------------------------------
 

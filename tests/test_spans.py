@@ -12,6 +12,7 @@ from __future__ import annotations
 import ast
 import glob
 import importlib.util
+import math
 import os
 import subprocess
 import sys
@@ -31,6 +32,7 @@ HOST_PATH = ["core/server.py", "core/client.py", "serve/store_engine.py",
 SPANS = [
     "ciao.serve.query", "ciao.scan.resolve", "ciao.scan.prepare",
     "ciao.plane.sync", "ciao.scan.params", "ciao.scan.launch",
+    "ciao.scan.pack", "ciao.scan.upload", "ciao.scan.dispatch",
     "ciao.scan.fetch", "ciao.scan.assemble", "ciao.client.encode",
     "ciao.client.pushdown", "ciao.serve.submit", "ciao.serve.apply",
     "ciao.store.lock_wait", "ciao.store.ingest", "ciao.store.parse",
@@ -137,6 +139,22 @@ def _lower_scan():
         s((Q, S1), f32), r_blk=128, interpret=True)
 
 
+def _lower_scan_packed():
+    from repro.kernels.scan_fused import (
+        _scan_core_pallas_packed, scan_args_layout,
+    )
+    i32, u8 = jnp.int32, jnp.uint8
+    K, N, T, C, Q, S1, Ts, L = 8, 256, 8, 8, 8, 128, 1, 64
+    layout = scan_args_layout(T, K, C, Q, S1, Ts, L)
+    off, shape, _ = layout[-1]
+    s = jax.ShapeDtypeStruct
+    return _scan_core_pallas_packed.lower(
+        s((K, N), u8), s((K, N), u8), s((K, N), u8), s((K, N), u8),
+        s((K, N), i32), s((K, N), i32), s((N,), i32), s((N,), jnp.uint32),
+        s((off + math.prod(shape),), i32),
+        layout=layout, r_blk=128, interpret=True)
+
+
 def _lower_pushdown():
     from repro.kernels.fused import clause_bitvectors_fused
     i32 = jnp.int32
@@ -160,3 +178,14 @@ def test_program_names_hold_what_the_kernel_metrics_look_for():
     for metric, program in programs.items():
         head = names[metric.split("_", 1)[0]]
         assert head.startswith(f"module @jit_{program}"), (metric, head)
+
+
+def test_packed_scan_program_name_holds_the_scan_kernel_metrics_program():
+    """The served launch runs ``_scan_core_pallas_packed``: its module's
+    name still holds what every scan kernel metric looks for."""
+    head = _lower_scan_packed().as_text().splitlines()[0]
+    scan = {m: p for m, p in _programs().items() if m.startswith("scan_")}
+    assert scan
+    for metric, program in scan.items():
+        assert head.startswith("module @jit_") and program in \
+            head.split()[1], (metric, head)

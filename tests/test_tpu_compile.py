@@ -117,6 +117,25 @@ def test_scan_kernel_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < (256 << 20)   # no lane-padded blowup
 
 
+def test_packed_scan_compiles_for_v5e(one_chip):
+    """``_scan_core_pallas_packed``, the served launch: the same plane
+    and batch buckets, every parameter table in one packed int32 buffer
+    unpacked in the program; the kernel keeps its name."""
+    from repro.kernels.scan_fused import (
+        _scan_core_pallas_packed, scan_args_layout,
+    )
+
+    layout = scan_args_layout(T, N_KEYS, C, Q, S1, TS, LUT)
+    off, shape, _ = layout[-1]
+    spec = _sds(one_chip)
+    compiled = _scan_core_pallas_packed.lower(
+        *_scan_args(spec)[:8], spec((off + int(np.prod(shape)),), jnp.int32),
+        layout=layout, r_blk=512, interpret=False).compile()
+    assert _kernel_named(compiled.as_text(), "_scan_core_pallas")
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (256 << 20)
+
+
 def test_spmd_scan_compiles_for_v5e_2x2(topo, one_chip):
     """The sharded plane's SPMD launch: one scan kernel per chip of a
     four-chip host, every argument split on its leading axis."""
